@@ -13,7 +13,8 @@ Two row families:
   path — the autotuner measures each op under its winning variant and
   ``roofline.hot_path_roofline`` turns the analytic bytes/flops model
   (``autotune.hot_path_traffic``) into a fraction-of-memory-ceiling row.
-  Always emitted (no dry-run files needed), both store layouts.
+  Emitted on devices with published peaks (``mesh.DEVICE_PEAKS``), both
+  store layouts; elsewhere one "not measured" row.
 """
 from __future__ import annotations
 
@@ -32,12 +33,20 @@ RESULTS = [
 def _hot_path_rows() -> List[Row]:
     import dataclasses
 
+    import jax
+
     from repro.core.engine import EngineConfig
     from repro.launch.autotune import hot_path_traffic, measure_plan
+    from repro.launch.mesh import DEVICE_PEAKS
     from repro.launch.roofline import hot_path_roofline
 
     from .bench_autotune import _tuned_key
 
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        # a roofline share needs the device's own peaks: none here
+        return [("roofline_hot", 0.0,
+                 f"not measured: no published peaks for {kind!r}")]
     rows: List[Row] = []
     base = EngineConfig(query_capacity=1 << 13, cooc_capacity=1 << 15,
                         session_capacity=1 << 13)

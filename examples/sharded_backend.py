@@ -1,9 +1,11 @@
-"""Beyond-paper example: the SHARDED search-assistance backend on 8 virtual
-devices — key-sharded stores, all_to_all pair routing, hot-key salting, and
-shard-merged suggestions (removes the paper's §4.4 memory wall).
+"""Beyond-paper example: the SHARDED search-assistance backend over every
+device the process sees (a power of two of them) — key-sharded stores,
+all_to_all pair routing, hot-key salting, and shard-merged suggestions
+(removes the paper's §4.4 memory wall).
 
   PYTHONPATH=src python examples/sharded_backend.py
-(sets the 8-device XLA flag itself; run as a fresh process)
+On the CPU it asks for 8 virtual devices itself (run as a fresh process);
+on a TPU host it shards over the chips.
 """
 import os
 
@@ -22,7 +24,9 @@ from repro.data.stream import StreamConfig, SyntheticStream  # noqa: E402
 
 
 def main() -> None:
-    mesh = Mesh(np.array(jax.devices()).reshape(8), ("shard",))
+    devs = jax.devices()
+    n = 1 << (len(devs).bit_length() - 1)      # largest power of two
+    mesh = Mesh(np.array(devs[:n]), ("shard",))
     ecfg = EngineConfig(query_capacity=1 << 13, cooc_capacity=1 << 16,
                         session_capacity=1 << 13, decay_every=4, rank_every=0)
     scfg = se.ShardedConfig(base=ecfg, n_salts=2, hot_threshold=40.0,
@@ -45,7 +49,7 @@ def main() -> None:
             state = decay(state, jnp.int32(ecfg.decay_every))
         state = state._replace(tick=state.tick + 1)
 
-    per_shard = np.asarray(state.cooc.live_mask).reshape(8, -1).sum(axis=1)
+    per_shard = np.asarray(state.cooc.live_mask).reshape(n, -1).sum(axis=1)
     print("per-shard cooccurrence entries:", per_shard.tolist())
     print("route-buffer drops:", np.asarray(state.n_route_drop).tolist())
     sugg = se.merge_sharded_suggestions(rank(state), ecfg.rank.top_k)

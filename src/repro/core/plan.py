@@ -135,10 +135,9 @@ def shape_class(cfg, backend: Optional[str] = None,
     import jax
     b = backend if backend is not None else jax.default_backend()
     if device_kind is None:
-        try:
-            device_kind = jax.devices(b)[0].device_kind
-        except Exception:
-            device_kind = "unknown"
+        # no fallback: a plan keyed to an unknown device would be reused
+        # on whatever device next fails the same lookup
+        device_kind = jax.devices(b)[0].device_kind
     dk = str(device_kind).replace(" ", "-").replace("/", "-").lower()
     parts = [b, dk,
              f"q{cfg.query_capacity.bit_length() - 1}",

@@ -33,24 +33,25 @@ Selection — three implementations of the same per-source top-k contract:
     open-addressing placement is a hash-derived bucket that is collision-free
     across live keys, so no two sources share a bucket). Gate-passing rows
     are stream-compacted (prefix-sum scatter, no sort) into a selection
-    arena, grouped by ONE flat u32 key — bucket id in the high bits, coarse
-    inverted score bits below, so each bucket's best rows lead its run —
-    and laid out as a dense ``[buckets, L]`` grid by pure gathers. The
+    arena, grouped by ONE two-key u32 sort — bucket id, then the inverted
+    exact score bits, so each bucket's best rows lead its run — and laid
+    out as a dense ``[buckets, L]`` grid by pure gathers. The
     per-bucket partial selection (top-k / iterated masked argmax along the
     L axis, Pallas kernel variant in ``kernels/topk_select.py``) then runs
     fully vectorized. The capacity-sized f32 ``argsort`` and the 3-key
     lexsort of the old pipeline are both gone: the only remaining sort is
-    the single flat u32 grouping key over the compacted arena, so cycle
-    cost scales with gate-passing rows, not table capacity.
+    the grouping sort over the compacted arena, so cycle cost scales with
+    gate-passing rows, not table capacity.
   * :func:`ranking_cycle_lexsort` — the pre-segmented reference pipeline
     (compact-by-argsort + 3-key lexsort + run extraction), kept verbatim for
     parity tests and before/after benchmark rows.
 
-Exactness: selection within a bucket uses exact scores (``lax.top_k`` over
-the gathered grid). Rows beyond the per-bucket arena ``L`` are cut by
-*coarse-score* order, so a true top-k member is lost only when >= L rows of
-one bucket land in the same coarse-score quantum — and every cut row is
-counted in ``SuggestionTable.n_overflow``, never silent.
+Exactness: rows beyond the per-bucket arena ``L`` are cut in exact-score
+order, so with ``L >= top_k`` no true top-k member is ever cut; every cut
+row is counted in ``SuggestionTable.n_overflow``, never silent. (The
+grouping key used to pack bucket id and score into one u32: at a 2^22-slot
+query store only 9 score bits were left — about the exponent — and head
+sources lost top-k members to the cut.)
 
 Cadence model under the **lazy** decay policy (``DecayConfig.policy ==
 "lazy"``): the ranking cycle is a *read*, so it applies the read-time decayed
@@ -70,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import stores
+from ..kernels.assoc_score import llr_g2
 from .decay import lazy_decayed
 from .plan import TunedPlan
 from .stores import HashTable, RegionTable
@@ -107,7 +109,7 @@ class RankConfig:
     # counted in n_overflow. >= 1.0 disables compaction.
     seg_arena_frac: float = 0.5
     # segmented path: per-bucket arena width L — a source's gate-passing
-    # rows beyond its L coarse-score-best are cut and counted.
+    # rows beyond its L best are cut and counted.
     bucket_rows: int = 64
     # max sources emitted per cycle (grid height cap; sources beyond it are
     # cut and counted in n_overflow). 0 (the default) derives the cap from
@@ -128,10 +130,6 @@ class RankConfig:
         if self.plan is not None:
             return self.plan.uses_kernel(op)
         return False
-
-
-def _xlogx(x):
-    return jnp.where(x > 0, x * jnp.log(jnp.maximum(x, 1e-30)), 0.0)
 
 
 def assoc_scores_jnp(w_ab, c_ab, w_a, w_b, c_a, c_b, total_w, total_c):
@@ -157,12 +155,7 @@ def assoc_scores_jnp(w_ab, c_ab, w_a, w_b, c_a, c_b, total_w, total_c):
     n = jnp.maximum(k11 + k12 + k21 + k22, eps)
     row1, row2 = k11 + k12, k21 + k22
     col1, col2 = k11 + k21, k12 + k22
-    llr = 2.0 * (
-        _xlogx(k11) + _xlogx(k12) + _xlogx(k21) + _xlogx(k22)
-        - _xlogx(row1) - _xlogx(row2) - _xlogx(col1) - _xlogx(col2)
-        + _xlogx(n)
-    )
-    llr = jnp.maximum(llr, 0.0)
+    llr = jnp.maximum(llr_g2(k11, k12, k21, k22), 0.0)
     denom = jnp.maximum(row1 * row2 * col1 * col2, eps)
     chi2 = n * (k11 * k22 - k12 * k21) ** 2 / denom
     valid = c_ab > 0
@@ -262,8 +255,8 @@ def ranking_cycle(
     """One full ranking cycle — segmented top-k (the fast path).
 
     Pipeline (see module docstring): score+gate -> prefix-sum compaction of
-    gate-passing row ids into an arena of M rows -> ONE flat u32 grouping
-    sort (bucket id | coarse inverted score) -> dense [R, L] bucket grid by
+    gate-passing row ids into an arena of M rows -> ONE grouping sort on
+    (bucket id, inverted exact score) -> dense [R, L] bucket grid by
     gathers -> exact per-bucket top-k. Output rows are indexed by bucket
     run, so the table has ``min(Q, M, cfg.max_sources)`` rows; empty rows
     keep the (0, 0) src key and are skipped by :func:`suggestions_to_host`.
@@ -299,15 +292,12 @@ def ranking_cycle(
         s = jnp.where(filled, score[safe_idx], -jnp.inf)
         seg = jnp.where(filled, src_slot[safe_idx], Q)
 
-    # ---- ONE flat u32 grouping key: bucket id (with one extra bit for the
-    # empty/gated sentinel Q) above coarse inverted score bits, so each
-    # bucket's rows are contiguous, best-first by coarse score. ----
-    bbits = Q.bit_length()            # log2(Q) + 1: room for the sentinel
-    qbits = 32 - bbits
-    key = (seg.astype(jnp.uint32) << jnp.uint32(qbits)) \
-        | ((~_sortable_f32(s)) >> jnp.uint32(bbits))
-    skey, sidx = jax.lax.sort((key, idx), num_keys=1, is_stable=True)
-    sseg = skey >> jnp.uint32(qbits)
+    # ---- ONE grouping sort on two u32 keys: bucket id (the empty/gated
+    # sentinel Q sorts last), then the inverted exact score, so each
+    # bucket's rows are contiguous, best-first. ----
+    sseg, _, sidx = jax.lax.sort(
+        (seg.astype(jnp.uint32), ~_sortable_f32(s), idx), num_keys=2,
+        is_stable=True)
     valid_row = sseg < Q
     is_new = jnp.concatenate(
         [jnp.ones((1,), bool), sseg[1:] != sseg[:-1]]) & valid_row
@@ -450,8 +440,8 @@ def ranking_cycle_region(
     followed by a per-source merge of the spill chain's ``max_chain * K``
     candidates. Tie order (documented): within a region, the lower slot
     position wins (insertion order); across a chain, the earlier chain
-    region wins — both may differ from the segmented path's coarse-score
-    arena order on exact ties.
+    region wins — both may differ from the segmented path's table-position
+    order on exact ties.
 
     Every live pair sits in exactly one region, so selection never cuts;
     ``n_overflow`` counts gate-passing pairs of sources beyond

@@ -34,8 +34,8 @@ from typing import Dict, List, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from . import ranking, stores
 from .decay import (prune_sweep, region_decay_sweep, region_prune_sweep,
@@ -81,22 +81,34 @@ def _stack_shards(tree, n):
 
 def init_sharded_state(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"
                        ) -> ShardedState:
+    """A fresh sharded state, built in place: one jitted program whose
+    output shardings are the state's own, so each device materializes only
+    its shard (the whole stacked state never sits on one device — at a
+    deployment-size cooc store it would not fit there)."""
     n = mesh.shape[axis]
     base = cfg.base
-    qstore = stores.make_table(base.query_capacity, {
-        "weight": jnp.float32, "count": jnp.float32, "last_tick": jnp.int32})
-    # region layout: each shard gets its own region pool + a full-Q chain
-    # directory (the qstore is replicated, so slot ids are global).
-    cooc = make_cooc_store(base, capacity=base.cooc_capacity // n)
-    sessions = stores.make_session_table(base.session_capacity // n,
-                                         base.session_window)
-    return ShardedState(
-        qstore=qstore,
-        cooc=_stack_shards(cooc, n),
-        sessions=_stack_shards(sessions, n),
-        tick=jnp.zeros((), jnp.int32),
-        n_route_drop=jnp.zeros((n,), jnp.int32),
-    )
+
+    def build() -> ShardedState:
+        qstore = stores.make_table(base.query_capacity, {
+            "weight": jnp.float32, "count": jnp.float32,
+            "last_tick": jnp.int32})
+        # region layout: each shard gets its own region pool + a full-Q
+        # chain directory (the qstore is replicated, so slot ids are global).
+        cooc = make_cooc_store(base, capacity=base.cooc_capacity // n)
+        sessions = stores.make_session_table(base.session_capacity // n,
+                                             base.session_window)
+        return ShardedState(
+            qstore=qstore,
+            cooc=_stack_shards(cooc, n),
+            sessions=_stack_shards(sessions, n),
+            tick=jnp.zeros((), jnp.int32),
+            n_route_drop=jnp.zeros((n,), jnp.int32),
+        )
+
+    shardings = jax.tree.map(lambda p: NamedSharding(mesh, p),
+                             _state_spec(cfg, axis),
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(build, out_shardings=shardings)()
 
 
 def _route(pairs_key_hi, pairs_key_lo, owner, payload: Dict[str, jax.Array],
@@ -211,7 +223,7 @@ def make_sharded_step(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
     fn = shard_map(body, mesh=mesh,
                    in_specs=(state_spec, rep, rep, rep, rep, rep, rep),
                    out_specs=state_spec,
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 
@@ -274,7 +286,7 @@ def make_sharded_tick_step(cfg: ShardedConfig, mesh: Mesh,
     state_spec = _state_spec(cfg, axis)
     fn = shard_map(body, mesh=mesh,
                    in_specs=(state_spec, rep, rep, rep, rep, rep, rep),
-                   out_specs=state_spec, check_rep=False)
+                   out_specs=state_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -309,7 +321,7 @@ def make_sharded_ingest_many(cfg: ShardedConfig, mesh: Mesh,
     state_spec = _state_spec(cfg, axis)
     fn = shard_map(many, mesh=mesh,
                    in_specs=(state_spec, rep, rep, rep, rep, rep, rep),
-                   out_specs=state_spec, check_rep=False)
+                   out_specs=state_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -349,7 +361,7 @@ def make_sharded_decay(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
     rep, sh = P(), P(axis)
     state_spec = _state_spec(cfg, axis)
     fn = shard_map(body, mesh=mesh, in_specs=(state_spec, rep),
-                   out_specs=state_spec, check_rep=False)
+                   out_specs=state_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -367,7 +379,7 @@ def make_sharded_rank(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
     out_spec = SuggestionTable(*([P(axis)] * 5), n_rows=P(axis),
                                n_overflow=P(axis))
     fn = shard_map(body, mesh=mesh, in_specs=(state_spec,),
-                   out_specs=out_spec, check_rep=False)
+                   out_specs=out_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -579,6 +591,29 @@ def export_sharded_pairs(cfg: ShardedConfig, state: ShardedState
             cols[k].append(e[k])
     merged = {k: np.concatenate(v) for k, v in cols.items()}
     return _merge_duplicate_pairs(cfg.base, merged)
+
+
+def split_sources(cfg: ShardedConfig, state: ShardedState) -> set:
+    """Source fingerprints with a (src, dst) pair held by more than one
+    shard. A source that turns hot mid-stream keeps its earlier pairs in
+    its owner shard while later ones route to salted shards; each shard
+    scores its fragment alone and :func:`merge_sharded_suggestions` keeps
+    the larger score, so such a source ranks below the unsharded engine
+    until a reshard sums the fragments (``_merge_duplicate_pairs``)."""
+    from .hashing import join_fp
+    n = state.n_route_drop.shape[0]
+    src, dst = [], []
+    for i in range(n):
+        tab = _shard_view(state.cooc, i, n)
+        e = (_export_region_pairs(tab, state.qstore)
+             if cfg.base.region_cooc else _export_hash_pairs(tab))
+        src.append(join_fp(e["src_hi"], e["src_lo"]))
+        dst.append(join_fp(e["dst_hi"], e["dst_lo"]))
+    src, dst = np.concatenate(src), np.concatenate(dst)
+    order = np.lexsort((dst, src))
+    s, d = src[order], dst[order]
+    dup = (s[1:] == s[:-1]) & (d[1:] == d[:-1])
+    return {int(x) for x in s[1:][dup]}
 
 
 def export_sharded_sessions(state: ShardedState) -> Dict[str, np.ndarray]:

@@ -21,8 +21,31 @@ from . import resolve_interpret
 from .decay_prune import LANE, SUBLANE, TILE, ROWS_PER_BLOCK
 
 
-def _xlogx(x):
-    return jnp.where(x > 0, x * jnp.log(jnp.maximum(x, 1e-30)), 0.0)
+def llr_g2(k11, k12, k21, k22):
+    """Dunning's G² of the 2x2 count table, in f32 without cancellation.
+
+    The textbook form sums ``x·ln(x)`` over the cells, the margins and the
+    total: terms of size ``n·ln(n)`` whose f32 rounding leaves an absolute
+    error of ``~1e-7·n·ln(n)`` in a statistic of order one (0.1 at
+    ``n ≈ 1.4e5`` events, more with a chip's less exact ``log``). Each cell
+    instead contributes ``k·ln(k·n / (R·C))`` for its row and column sums,
+    and ``k·n − R·C = ±D`` with ``D = k11·k22 − k12·k21`` (plus on the
+    diagonal), so ``G² = 2·Σ k·log1p(±D / (R·C))``: small terms, nothing
+    to cancel. Pure jnp on values, usable inside a Pallas kernel.
+    """
+    d = k11 * k22 - k12 * k21
+    r1, r2 = k11 + k12, k21 + k22
+    q1, q2 = k11 + k21, k12 + k22
+
+    def cell(k, r, q, x_num):
+        x = x_num / jnp.maximum(r * q, 1e-9)
+        # k > 0 keeps k·n/(R·C) = 1 + x away from 0; the floor only guards
+        # the rounding of x
+        return jnp.where(
+            k > 0, k * jnp.log1p(jnp.maximum(x, -1.0 + 1e-7)), 0.0)
+
+    return 2.0 * (cell(k11, r1, q1, d) + cell(k12, r1, q2, -d)
+                  + cell(k21, r2, q1, -d) + cell(k22, r2, q2, d))
 
 
 def score_body(w_ab, c_ab, w_a, w_b, c_a, c_b, total_w, total_c,
@@ -50,10 +73,7 @@ def score_body(w_ab, c_ab, w_a, w_b, c_a, c_b, total_w, total_c,
     n = jnp.maximum(k11 + k12 + k21 + k22, eps)
     r1, r2 = k11 + k12, k21 + k22
     q1, q2 = k11 + k21, k12 + k22
-    llr = 2.0 * (_xlogx(k11) + _xlogx(k12) + _xlogx(k21) + _xlogx(k22)
-                 - _xlogx(r1) - _xlogx(r2) - _xlogx(q1) - _xlogx(q2)
-                 + _xlogx(n))
-    llr = jnp.maximum(llr, 0.0)
+    llr = jnp.maximum(llr_g2(k11, k12, k21, k22), 0.0)
     chi2 = n * (k11 * k22 - k12 * k21) ** 2 / jnp.maximum(r1 * r2 * q1 * q2, eps)
     valid = c_ab > 0
     condprob = jnp.where(valid, condprob, 0.0)

@@ -16,9 +16,11 @@ per aux lane.
 
 TPU layout: the 1-D table arrays (capacity C, a power of two) are viewed as
 (C/1024, 8, 128) so each block is an aligned (8, 128) VPU tile; the grid
-walks row-blocks of ROWS_PER_BLOCK tiles. Stats are accumulated per grid
-step into a small (grid,)-shaped output and reduced on the host side of the
-call (one extra tiny pass).
+walks row-blocks of ROWS_PER_BLOCK tiles. The live count and total weight
+are jnp reductions over the kernel's outputs: the same reductions, in the
+same order, as the jnp sweep, so both paths return bit-identical scalars
+(a per-block stats output would round differently, and rank-1 per-block
+outputs do not tile on the TPU).
 
 ``interpret`` defaults to auto-detection: the kernel compiles for real on a
 TPU backend and falls back to the Pallas interpreter elsewhere (CPU CI).
@@ -48,7 +50,7 @@ def _make_kernel(n_w: int, n_aux: int):
     """Build the fused sweep kernel for n_w weight lanes + n_aux aux lanes.
 
     Ref order: inputs  [f, thresh, key_hi, key_lo, w_0..w_{n_w-1}, a_0..]
-               outputs [key_hi', key_lo', w'_0.., a'_0.., live, tot]
+               outputs [key_hi', key_lo', w'_0.., a'_0..]
     """
     def kernel(*refs):
         f = refs[0][0]
@@ -61,8 +63,6 @@ def _make_kernel(n_w: int, n_aux: int):
         out_hi_ref, out_lo_ref = refs[o], refs[o + 1]
         w_out_refs = [refs[o + 2 + i] for i in range(n_w)]
         a_out_refs = [refs[o + 2 + n_w + i] for i in range(n_aux)]
-        live_ref = refs[o + 2 + n_w + n_aux]
-        tot_ref = refs[o + 3 + n_w + n_aux]
 
         live = (k_hi != 0) | (k_lo != 0)
         w0 = w_ins[0] * f
@@ -75,8 +75,6 @@ def _make_kernel(n_w: int, n_aux: int):
             w_out_refs[i][...] = jnp.where(keep, w_ins[i] * f, 0.0)
         for a_in, a_out in zip(a_ins, a_out_refs):
             a_out[...] = jnp.where(keep, a_in, jnp.zeros_like(a_in))
-        live_ref[0] = jnp.sum(keep.astype(jnp.float32))
-        tot_ref[0] = jnp.sum(w0)
 
     return kernel
 
@@ -119,32 +117,29 @@ def decay_prune_multi(
     n_w, n_aux = len(weight_lanes), len(aux_lanes)
     spec = pl.BlockSpec((blk, SUBLANE, LANE), lambda i: (i, 0, 0))
     sspec = pl.BlockSpec((1,), lambda i: (0,))
-    stat_spec = pl.BlockSpec((1,), lambda i: (i,))
 
     lane_out = lambda a: jax.ShapeDtypeStruct(shape3, a.dtype)
     outs = pl.pallas_call(
         _make_kernel(n_w, n_aux),
         grid=(grid,),
         in_specs=[sspec, sspec, spec, spec] + [spec] * (n_w + n_aux),
-        out_specs=[spec, spec] + [spec] * (n_w + n_aux) + [stat_spec, stat_spec],
+        out_specs=[spec, spec] + [spec] * (n_w + n_aux),
         out_shape=[
             jax.ShapeDtypeStruct(shape3, jnp.uint32),
             jax.ShapeDtypeStruct(shape3, jnp.uint32),
             *[lane_out(w) for w in weight_lanes],
             *[lane_out(a) for a in aux_lanes],
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(f, t, view(key_hi), view(key_lo),
       *[view(w) for w in weight_lanes], *[view(a) for a in aux_lanes])
 
-    out_hi, out_lo = outs[0], outs[1]
+    out_hi, out_lo = outs[0].reshape(C), outs[1].reshape(C)
     w_out = tuple(o.reshape(C) for o in outs[2:2 + n_w])
-    a_out = tuple(o.reshape(C) for o in outs[2 + n_w:2 + n_w + n_aux])
-    live_p, tot_p = outs[-2], outs[-1]
-    return (out_hi.reshape(C), out_lo.reshape(C), w_out, a_out,
-            jnp.sum(live_p).astype(jnp.int32), jnp.sum(tot_p))
+    a_out = tuple(o.reshape(C) for o in outs[2 + n_w:])
+    keep = (out_hi != 0) | (out_lo != 0)
+    return (out_hi, out_lo, w_out, a_out,
+            jnp.sum(keep.astype(jnp.int32)), jnp.sum(w_out[0]))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

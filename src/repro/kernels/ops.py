@@ -62,13 +62,6 @@ def decay_prune_table(table, dticks, *, cfg, weight_lanes: Tuple[str, ...]):
             lanes[name] = w
         for name, a in zip(aux_1d, a_out):
             lanes[name] = a
-        # Recompute the scalar totals with the same jnp reductions as the
-        # reference sweep (``decay._apply_decay_prune``): the in-kernel
-        # per-block partial sums round differently, and these two scalars
-        # were the ONLY leaves breaking bit-exact kernel-vs-jnp engine
-        # parity. The lanes themselves are exact.
-        live = jnp.sum(keep.astype(jnp.int32))
-        tot = jnp.sum(lanes[primary])
     # multi-dim lanes (none in the engine stores today) still need a mask
     for name, lane in lanes.items():
         if name not in weight_lanes and lane.ndim > 1:

@@ -25,7 +25,7 @@ scatter. Dispatch in ``ops.score_gate`` / ``ops.bucket_topk``, oracles in
 ``ref.py``.
 
 Layout mirrors decay_prune: (C/1024, 8, 128) tiles, 1-D grid for
-``score_gate``; (rows, 128-padded lanes) blocks for ``bucket_topk``. The
+``score_gate``; transposed (L, buckets) blocks for ``bucket_topk``. The
 in-kernel lazy decay covers the (default) exponential kind; other kinds
 pre-decay in jnp before the call.
 """
@@ -126,21 +126,22 @@ def score_gate(w_ab, c_ab, w_a, w_b, c_a, c_b, ok, last_tick, total_w,
 # bucket_topk: per-bucket iterated masked argmax over the [R, L] grid.
 # ---------------------------------------------------------------------------
 
-_BUCKET_BLOCK = 128   # bucket rows per grid step
+_BUCKET_BLOCK = 128   # region rows per region_rank grid step
+_BUCKET_LANES = 1024  # bucket columns per bucket_topk grid step
 
 
-def _make_bucket_kernel(K: int, Lp: int, Kp: int):
+def _make_bucket_kernel(K: int, Lp: int):
     def kernel(g_ref, vals_ref, args_ref):
-        g = g_ref[...]                                   # (BR, Lp)
-        iota = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+        g = g_ref[...]                                   # (Lp, BR)
+        iota = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
         vals_ref[...] = jnp.full(vals_ref.shape, -jnp.inf, jnp.float32)
         args_ref[...] = jnp.full(args_ref.shape, Lp, jnp.int32)
         for k in range(K):
-            m = jnp.max(g, axis=1, keepdims=True)
+            m = jnp.max(g, axis=0, keepdims=True)        # (1, BR)
             hit = (g == m) & (m > -jnp.inf)
-            am = jnp.min(jnp.where(hit, iota, Lp), axis=1, keepdims=True)
-            vals_ref[:, k] = m[:, 0]
-            args_ref[:, k] = am[:, 0]
+            am = jnp.min(jnp.where(hit, iota, Lp), axis=0, keepdims=True)
+            vals_ref[k:k + 1, :] = m
+            args_ref[k:k + 1, :] = am
             g = jnp.where(iota == am, -jnp.inf, g)       # retire the winner
 
     return kernel
@@ -150,7 +151,14 @@ def _make_bucket_kernel(K: int, Lp: int, Kp: int):
 def bucket_topk(grid: jax.Array, k: int, *, interpret: bool | None = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """Top-k of each bucket row of ``grid`` [R, L] by K rounds of masked
-    argmax — each block of bucket rows stays in VMEM for all K rounds.
+    argmax — each block of buckets stays in VMEM for all K rounds.
+
+    The kernel sees the grid transposed, ``[L, R]``: buckets run along the
+    128-wide lane axis and each round's max/argmax reduces over sublanes,
+    so the K winners of a block leave as lane-dense ``(K, buckets)`` rows.
+    Padding is to 8 rows of L and 8 rows of K only (the untransposed form
+    padded both to 128 lanes, several times the grid's own bytes at a
+    deployment-size query store).
 
     Ties resolve to the lowest column (identical to ``lax.top_k``).
     Returns (vals f32[R, k], args i32[R, k]); exhausted rounds yield
@@ -158,25 +166,25 @@ def bucket_topk(grid: jax.Array, k: int, *, interpret: bool | None = None
     """
     interpret = resolve_interpret(interpret)
     R, L = grid.shape
-    Lp = ((max(L, 1) + LANE - 1) // LANE) * LANE
-    Kp = ((max(k, 1) + LANE - 1) // LANE) * LANE
-    BR = min(_BUCKET_BLOCK, max(SUBLANE, R))
+    Lp = ((max(L, 1) + SUBLANE - 1) // SUBLANE) * SUBLANE
+    Kp = ((max(k, 1) + SUBLANE - 1) // SUBLANE) * SUBLANE
+    BR = min(_BUCKET_LANES, ((max(R, 1) + LANE - 1) // LANE) * LANE)
     Rp = ((R + BR - 1) // BR) * BR
-    gp = jnp.full((Rp, Lp), -jnp.inf, jnp.float32)
-    gp = gp.at[:R, :L].set(grid.astype(jnp.float32))
+    gp = jnp.full((Lp, Rp), -jnp.inf, jnp.float32)
+    gp = gp.at[:L, :R].set(grid.astype(jnp.float32).T)
 
-    spec_in = pl.BlockSpec((BR, Lp), lambda i: (i, 0))
-    spec_out = pl.BlockSpec((BR, Kp), lambda i: (i, 0))
+    spec_in = pl.BlockSpec((Lp, BR), lambda i: (0, i))
+    spec_out = pl.BlockSpec((Kp, BR), lambda i: (0, i))
     vals, args = pl.pallas_call(
-        _make_bucket_kernel(int(k), Lp, Kp),
+        _make_bucket_kernel(int(k), Lp),
         grid=(Rp // BR,),
         in_specs=[spec_in],
         out_specs=[spec_out, spec_out],
-        out_shape=[jax.ShapeDtypeStruct((Rp, Kp), jnp.float32),
-                   jax.ShapeDtypeStruct((Rp, Kp), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((Kp, Rp), jnp.float32),
+                   jax.ShapeDtypeStruct((Kp, Rp), jnp.int32)],
         interpret=interpret,
     )(gp)
-    return vals[:R, :k], args[:R, :k]
+    return vals[:k, :R].T, args[:k, :R].T
 
 
 # ---------------------------------------------------------------------------
@@ -201,12 +209,12 @@ def _make_region_kernel(K: int, Wp: int,
         if half_life is not None:
             (w_ab_ref, c_ab_ref, w_a_ref, w_b_ref, c_a_ref, c_b_ref,
              ok_ref, lt_ref, tw_ref, tc_ref, now_ref,
-             vals_ref, args_ref, npass_ref) = refs
+             vals_ref, args_ref) = refs
             dt = jnp.maximum(now_ref[0] - lt_ref[...], 0.0)
             w_ab = w_ab_ref[...] * jnp.exp2(-dt / jnp.float32(half_life))
         else:
             (w_ab_ref, c_ab_ref, w_a_ref, w_b_ref, c_a_ref, c_b_ref,
-             ok_ref, tw_ref, tc_ref, vals_ref, args_ref, npass_ref) = refs
+             ok_ref, tw_ref, tc_ref, vals_ref, args_ref) = refs
             w_ab = w_ab_ref[...]
         c_ab = c_ab_ref[...]
         w_a = w_a_ref[...]
@@ -214,7 +222,6 @@ def _make_region_kernel(K: int, Wp: int,
                            c_b_ref[...], tw_ref[0], tc_ref[0], coefs)
         ok = ((ok_ref[...] > 0) & (w_ab >= mpw) & (c_ab >= mpc)
               & (w_a >= msw))
-        npass_ref[...] = jnp.sum(ok.astype(jnp.int32), axis=1)
         g = jnp.where(ok, score, -jnp.inf)
         iota = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
         vals_ref[...] = jnp.full(vals_ref.shape, -jnp.inf, jnp.float32)
@@ -226,6 +233,8 @@ def _make_region_kernel(K: int, Wp: int,
             vals_ref[:, k] = m[:, 0]
             args_ref[:, k] = am[:, 0]
             g = jnp.where(iota == am, -jnp.inf, g)
+        # the gate-pass count rides in the spare column K of the args block
+        args_ref[:, K] = jnp.sum(ok.astype(jnp.int32), axis=1)
 
     return kernel
 
@@ -250,13 +259,16 @@ def region_rank(w_ab, c_ab, w_a, w_b, c_a, c_b, ok, last_tick, total_w,
     interpret = resolve_interpret(interpret)
     R, W = w_ab.shape
     Wp = ((max(W, 1) + LANE - 1) // LANE) * LANE
-    Kp = ((max(k, 1) + LANE - 1) // LANE) * LANE
+    Kp = ((k + 1 + LANE - 1) // LANE) * LANE   # + the npass column
     BR = min(_BUCKET_BLOCK, max(SUBLANE, R))
     Rp = ((R + BR - 1) // BR) * BR
 
     def pad(x, fill=0.0):
+        x = x.astype(jnp.float32)
+        if (Rp, Wp) == (R, W):       # lane-aligned width: no padded copy
+            return x
         buf = jnp.full((Rp, Wp), fill, jnp.float32)
-        return buf.at[:R, :W].set(x.astype(jnp.float32))
+        return buf.at[:R, :W].set(x)
 
     args = [pad(a) for a in (w_ab, c_ab, w_a, w_b, c_a, c_b, ok)]
     scalars = [jnp.asarray(total_w, jnp.float32).reshape(1),
@@ -268,17 +280,15 @@ def region_rank(w_ab, c_ab, w_a, w_b, c_a, c_b, ok, last_tick, total_w,
     spec_in = pl.BlockSpec((BR, Wp), lambda i: (i, 0))
     spec_out = pl.BlockSpec((BR, Kp), lambda i: (i, 0))
     sspec = pl.BlockSpec((1,), lambda i: (0,))
-    npass_spec = pl.BlockSpec((BR,), lambda i: (i,))
-    vals, cols, npass = pl.pallas_call(
+    vals, cols = pl.pallas_call(
         _make_region_kernel(int(k), Wp, coefs, min_pair_weight,
                             min_src_weight, min_pair_count,
                             None if half_life is None else float(half_life)),
         grid=(Rp // BR,),
         in_specs=[spec_in] * len(args) + [sspec] * len(scalars),
-        out_specs=[spec_out, spec_out, npass_spec],
+        out_specs=[spec_out, spec_out],
         out_shape=[jax.ShapeDtypeStruct((Rp, Kp), jnp.float32),
-                   jax.ShapeDtypeStruct((Rp, Kp), jnp.int32),
-                   jax.ShapeDtypeStruct((Rp,), jnp.int32)],
+                   jax.ShapeDtypeStruct((Rp, Kp), jnp.int32)],
         interpret=interpret,
     )(*args, *scalars)
-    return vals[:R, :k], cols[:R, :k], npass[:R]
+    return vals[:R, :k], cols[:R, :k], cols[:R, k]

@@ -23,9 +23,12 @@ Contract
   JSON per shape class, under ``$REPRO_AUTOTUNE_CACHE`` (default
   ``~/.cache/repro-autotune``). A cache hit returns the stored plan with
   NO re-benchmarking.
-* Kernel candidates that raise (Pallas unavailable / unsupported backend)
-  are recorded as failed and the jnp reference wins — tuning degrades
-  gracefully to the all-jnp plan.
+* Off a native-Pallas backend, kernel candidates that raise (Pallas
+  unavailable under the interpreter) are recorded as failed and the jnp
+  reference wins — tuning degrades gracefully to the all-jnp plan. On a
+  native backend (TPU) a raising kernel is a bug, not a jnp win: the
+  exception propagates, so a plan can never hide a kernel that does not
+  compile on the device it was tuned for.
 * Plans are **result-invariant** by construction: every candidate pair is
   property-tested bit-exact (``tests/test_autotune.py``), so the tuner can
   never change engine states or suggestion tables, only speed.
@@ -51,12 +54,15 @@ from ..core import ranking, stores
 from ..core.decay import sweep_decay_prune
 from ..core.plan import (HOT_PATH_OPS, JNP, KERNEL, TunedPlan,
                          default_region_width, shape_class)
+from ..kernels import kernels_native
 
 __all__ = ["tune", "tune_engine_config", "measure_plan", "cache_dir",
            "cache_path", "hot_path_traffic", "TunedPlan", "shape_class"]
 
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
-CACHE_VERSION = 1
+# bumped whenever a candidate's code changes, so no plan measured on old
+# kernels is served from the cache (2: TPU-compilable kernels)
+CACHE_VERSION = 2
 
 # score_gate tile-shape candidates (rows of 1024 slots per grid step).
 # Measured on CPU-interpret the spread is ~11x across this range; on TPU
@@ -305,11 +311,15 @@ def measure_plan(cfg, *, repeats: int = 3, tune_ingest: bool = True
     key = jax.random.PRNGKey(0)
     region = cfg.region_cooc
 
+    native = kernels_native()
+
     def bench(name: str, fn) -> Optional[float]:
         try:
             t = _time_us(fn, repeats)
-        except Exception:                     # Pallas unavailable / broken
-            timings[name] = None
+        except Exception:
+            if native:        # the device compiles kernels: never hide one
+                raise
+            timings[name] = None              # interpreter-only backend
             return None
         timings[name] = t
         return t

@@ -6,15 +6,40 @@ jax initialization)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding rules leave dims UNCONSTRAINED for the
+    # compiler to choose, which Explicit axes (the make_mesh default
+    # since JAX 0.8) reject.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-# TPU v5e hardware constants (per chip) for the roofline terms.
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW_PER_LINK = 50e9          # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_s": 819e9,
+                    "hbm_bytes": 16e9, "ici_link_bytes_s": 50e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; a device that is not in the
+    table is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(DEVICE_PEAKS)})"
+                       ) from None
+
+
+# The modeled v5e production mesh of the dry-run (virtual devices).
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16 = _V5E["bf16_flops"]       # FLOP/s
+HBM_BW = _V5E["hbm_bytes_s"]               # B/s
+ICI_BW_PER_LINK = _V5E["ici_link_bytes_s"]  # B/s per link

@@ -210,20 +210,27 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
 
 
 def hot_path_roofline(name: str, *, bytes_touched: float, flops: float,
-                      measured_us: float, peak=None, hbm=None) -> Dict:
+                      measured_us: float, device_kind: str | None = None
+                      ) -> Dict:
     """Distance-to-roofline row for ONE measured hot-path op.
 
     The tuned engine ops (``autotune.hot_path_traffic`` supplies the
     analytic bytes/flops) are table sweeps: the hardware ceiling for each
-    is ``max(bytes/HBM_BW, flops/peak)`` — no collectives, one device.
+    is ``max(bytes/hbm_bw, flops/peak)`` — no collectives, one device.
     ``roofline_fraction`` is ceiling-time over measured-time (1.0 = the op
-    runs as fast as the memory system allows; CPU-interpret numbers are
-    honest and small). Mirrors :meth:`Roofline.row` field names so both
-    row kinds land in the same reports.
+    runs as fast as the memory system allows). Mirrors
+    :meth:`Roofline.row` field names so both row kinds land in the same
+    reports. The peaks are those of ``device_kind`` (default: the first
+    device's), from ``mesh.DEVICE_PEAKS``; a device with no published
+    peaks raises.
     """
-    from .mesh import HBM_BW, PEAK_FLOPS_BF16
-    peak = peak or PEAK_FLOPS_BF16
-    hbm = hbm or HBM_BW
+    import jax
+
+    from .mesh import device_peaks
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    pk = device_peaks(device_kind)
+    peak, hbm = pk["bf16_flops"], pk["hbm_bytes_s"]
     t_mem = bytes_touched / hbm
     t_comp = flops / peak
     t_ceiling = max(t_mem, t_comp, 1e-30)

@@ -64,6 +64,7 @@ from ..serving.serve import SuggestFrontend, ServerSet, pack_suggestions
 from ..streaming import (FirehoseLogReader, FirehoseLogWriter, ReplayConfig,
                          FirehoseWorkload, SLOConfig, SpamSpec, SpikeSpec,
                          WorkloadConfig, recover_service, slow_io)
+from .compile_cache import use_compile_cache
 
 
 def _fmt(v, nd: int = 1):
@@ -173,6 +174,7 @@ def main() -> None:
                     help="compaction fallback depth: old bases (and their "
                          "log tail) retained after each floor swap")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.workload == "firehose":
         wl = FirehoseWorkload(WorkloadConfig(

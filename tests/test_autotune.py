@@ -166,6 +166,20 @@ def test_graceful_fallback_without_pallas(monkeypatch):
                    if k.endswith(":jnp"))
 
 
+def test_native_backend_kernel_failure_raises(monkeypatch):
+    """On a backend that compiles kernels natively, a raising kernel
+    candidate is a bug the tuner must surface, never a silent jnp win."""
+    from repro.kernels import ops as kops
+
+    def boom(*a, **k):
+        raise RuntimeError("kernel refused by the TPU compiler")
+
+    monkeypatch.setattr(kops, "score_gate", boom)
+    monkeypatch.setattr(autotune, "kernels_native", lambda: True)
+    with pytest.raises(RuntimeError, match="refused"):
+        autotune.measure_plan(_cfg(), repeats=1, tune_ingest=False)
+
+
 # ---------------------------------------------------------------------------
 # plans change performance only — engine results are plan-invariant
 # ---------------------------------------------------------------------------
@@ -218,3 +232,18 @@ def test_resolve_interpret():
     assert resolve_interpret(None) == (not native)
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Roofline rows use the named device's published peaks; a device kind
+    with none raises instead of borrowing another chip's."""
+    from repro.launch.mesh import DEVICE_PEAKS
+    from repro.launch.roofline import hot_path_roofline
+    pk = DEVICE_PEAKS["TPU v5 lite"]
+    row = hot_path_roofline("sweep", bytes_touched=pk["hbm_bytes_s"] * 1e-3,
+                            flops=0.0, measured_us=2000.0,
+                            device_kind="TPU v5 lite")
+    assert row["roofline_fraction"] == pytest.approx(0.5)
+    with pytest.raises(KeyError, match="no published peaks"):
+        hot_path_roofline("sweep", bytes_touched=1.0, flops=1.0,
+                          measured_us=1.0, device_kind="cpu")
