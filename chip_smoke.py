@@ -16,9 +16,14 @@ Phases, one result line each:
               candidate that raises fails the run
     [correct] SyntheticStream ticks through the engine at full capacities
               vs core/reference.ReferenceEngine (top-k sets, scores, drops)
-    [load]    the rt cooc store filled to >= 1/4 through ingest_many
+    [load]    SyntheticStream traffic (Zipf queries, topical sessions,
+              tweets) replayed through ingest_many until the rt cooc store
+              is >= 1/4 full
     [serve]   live ticks with an rt and a bg rank cycle, a log, a snapshot,
-              ServerSet requests; rank-cycle / export wall times, peak bytes
+              ServerSet requests; rank-cycle / export host wall times
+    [parity]  at that fill, the rank cycle and the decay sweep under the
+              tuned plan vs the plan with every kernel/jnp choice swapped
+    [serve]   peak device bytes
 
 ``--chips 4`` runs only the sharded engine over a 4-chip mesh (cooc 2^25
 per shard) against ReferenceEngine. The last stdout line is the JSON
@@ -35,7 +40,6 @@ import os
 import shutil
 import sys
 import time
-from functools import partial
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(ROOT, "src")
@@ -59,11 +63,13 @@ class Sizes:
     cooc_capacity: int = 1 << 25
     session_capacity: int = 1 << 21
     vocab: int = 1 << 19            # distinct query strings in the hose
-    sessions: int = 1 << 17         # live user sessions
+    users: int = 1 << 16            # users, one session each per epoch
     batch: int = 1 << 15            # query events per 10 s tick
     tweets: int = 512               # firehose tweets per tick
     grams: int = 16                 # n-gram slots per tweet
-    fill_ticks: int = 80            # replayed through ingest_many
+    # replayed through ingest_many (~29 min of hose); the live ticks then
+    # reach tick 180, where both rt (every 30) and bg (every 90) rank
+    fill_ticks: int = 176
     chunk_ticks: int = 8            # ticks per ingest_many dispatch
     correct_ticks: int = 20
     correct_batch: int = 4096
@@ -84,77 +90,27 @@ def state_bytes(state) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(state))
 
 
-# ---------------------------------------------------------------------------
-# the hose: query strings with host-side fingerprints, events made on device
-# ---------------------------------------------------------------------------
+def hose(sz: Sizes, seed: int):
+    """The deployment hose: ``SyntheticStream`` at a 2^19-query vocabulary
+    (Zipf 1.07 popularity, topic-sticky sessions, topical tweets)."""
+    from repro.data.stream import StreamConfig, SyntheticStream
+    return SyntheticStream(StreamConfig(
+        vocab_size=sz.vocab, n_users=sz.users, queries_per_tick=sz.batch,
+        tweets_per_tick=sz.tweets, tweet_grams=sz.grams), seed=seed)
 
-def vocab_texts_fps(n: int):
-    """Query strings ``q0000000..`` and their FNV-1a fingerprints — the
-    tokenizer's hash, vectorized (``hashing.fingerprint`` per string)."""
+
+def backlog(stream, t0: int, n_ticks: int):
+    """Ticks ``[t0, t0 + n_ticks)`` of ``stream`` as one device TickStack,
+    stacked as a replayed log chunk."""
     import numpy as np
-    from repro.core.hashing import FNV_OFFSET, FNV_PRIME, fingerprint
-    texts = np.char.mod("q%07d", np.arange(n))
-    raw = np.frombuffer(texts.astype("S8").tobytes(), np.uint8).reshape(n, 8)
-    h = np.full(n, FNV_OFFSET, np.uint64)
-    with np.errstate(over="ignore"):
-        for j in range(8):
-            h ^= raw[:, j].astype(np.uint64)
-            h *= np.uint64(FNV_PRIME)
-    h[h == 0] = 1
-    check(all(int(h[i]) == fingerprint(str(texts[i])) for i in (0, n - 1)),
-          "vectorized fingerprints disagree with hashing.fingerprint")
-    return texts, h
-
-
-def _power_law(key, shape, n):
-    """Indices in [0, n) with density ~ i^(-2/3): a heavy head (the top 1%
-    of queries draw ~22% of traffic) and a long tail."""
-    import jax
-    import jax.numpy as jnp
-    u = jax.random.uniform(key, shape)
-    return jnp.minimum((n * u ** 3).astype(jnp.int32), n - 1)
-
-
-def hose_stack(key, vocab_hi, vocab_lo, sz: Sizes, n_ticks: int):
-    """``n_ticks`` ticks of query hose + firehose as a device TickStack:
-    sessions drawn uniformly, queries and tweet grams by ``_power_law``,
-    interaction sources typed/hashtag/related at 70/22/8%."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core.engine import TickStack
-
-    @partial(jax.jit, static_argnums=(3,))
-    def make(key, vhi, vlo, shape):
-        n_ticks, B, T, G, S = shape
-        kq, ks, kc, kg = jax.random.split(key, 4)
-        n = vhi.shape[0]
-        q = _power_law(kq, (n_ticks, B), n)
-        sess = jax.random.randint(ks, (n_ticks, B), 0, S).astype(jnp.uint32)
-        src = jax.random.categorical(
-            kc, jnp.log(jnp.array([0.70, 0.22, 0.08])), shape=(n_ticks, B))
-        g = _power_law(kg, (n_ticks, T, G), n)
-        return TickStack(
-            sess_hi=sess + jnp.uint32(1),
-            sess_lo=sess * jnp.uint32(0x9E3779B1) + jnp.uint32(0x7F4A7C15),
-            q_hi=vhi[q], q_lo=vlo[q], src=src.astype(jnp.int32),
-            q_valid=jnp.ones((n_ticks, B), bool),
-            g_hi=vhi[g], g_lo=vlo[g], t_valid=jnp.ones((n_ticks, T), bool))
-
-    return make(key, vocab_hi, vocab_lo,
-                (n_ticks, sz.batch, sz.tweets, sz.grams, sz.sessions))
-
-
-def host_tick(stack):
-    """Tick 0 of a device TickStack as the host hose batches ``step`` takes."""
-    import numpy as np
-    from repro.core.hashing import join_fp
-    from repro.data.stream import QueryEvents, TweetBatch
-    h = {k: np.asarray(v[0]) for k, v in stack._asdict().items()}
-    ev = QueryEvents(sess_fp=join_fp(h["sess_hi"], h["sess_lo"]),
-                     q_fp=join_fp(h["q_hi"], h["q_lo"]), src=h["src"],
-                     valid=h["q_valid"])
-    tw = TweetBatch(grams=join_fp(h["g_hi"], h["g_lo"]), valid=h["t_valid"])
-    return ev, tw
+    from repro.streaming import LogChunk, chunk_to_stack
+    ticks = [stream.gen_tick(t) for t in range(t0, t0 + n_ticks)]
+    col = lambda f: np.stack([f(ev, tw) for ev, tw in ticks])
+    return chunk_to_stack(LogChunk(
+        ticks=np.arange(t0, t0 + n_ticks), sess_fp=col(lambda e, w: e.sess_fp),
+        q_fp=col(lambda e, w: e.q_fp), src=col(lambda e, w: e.src),
+        q_valid=col(lambda e, w: e.valid), grams=col(lambda e, w: w.grams),
+        t_valid=col(lambda e, w: w.valid)))
 
 
 # ---------------------------------------------------------------------------
@@ -267,8 +223,6 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
     import numpy as np
     from repro.core import ranking
     from repro.core.background import AssistanceService, background_config
-    from repro.core.hashing import split_fp
-    from repro.data.tokenizer import NGramTokenizer
     from repro.distributed.fault_tolerance import CheckpointManager
     from repro.serving.serve import SuggestFrontend, ServerSet, \
         pack_suggestions
@@ -280,16 +234,16 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
     resident = state_bytes(svc.rt.state) + state_bytes(svc.bg.state)
     print(f"[load] resident state rt+bg={resident} B "
           f"(rt={state_bytes(svc.rt.state)} B)", flush=True)
-
-    texts, fps = vocab_texts_fps(sz.vocab)
-    v_hi, v_lo = (jnp.asarray(a) for a in split_fp(fps))
-    key = jax.random.PRNGKey(seed)
+    s0 = time.perf_counter()
+    stream = hose(sz, seed)
+    print(f"[load] SyntheticStream vocab={len(stream.vocab)} "
+          f"users={sz.users} ({time.perf_counter() - s0:.1f}s to build)",
+          flush=True)
 
     # ---- load: replay a backlog through ingest_many (the catch-up path) ----
     t0 = time.perf_counter()
-    for c in range(sz.fill_ticks // sz.chunk_ticks):
-        stack = hose_stack(jax.random.fold_in(key, c), v_hi, v_lo, sz,
-                           sz.chunk_ticks)
+    for c in range(0, sz.fill_ticks, sz.chunk_ticks):
+        stack = backlog(stream, c, sz.chunk_ticks)
         svc.rt.step_many(stack)
         svc.bg.step_many(stack)        # same backlog, bg cadences
     jax.block_until_ready((svc.rt.state, svc.bg.state))
@@ -301,7 +255,8 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
           f"{sz.fill_ticks * sz.tweets} tweets): cooc live={live} "
           f"({frac:.3f} of slots), drops={int(svc.rt.state.cooc.n_dropped)}"
           f"; bg cooc live={int(jnp.sum(svc.bg.state.cooc.live_mask))} "
-          f"({dt:.1f}s for rt+bg incl. compiles)", flush=True)
+          f"({dt:.1f}s for rt+bg incl. compiles and host generation)",
+          flush=True)
     check(frac >= 0.25, f"cooc store only {frac:.3f} full")
 
     # ---- serve: live ticks through the whole stack ----
@@ -315,8 +270,7 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
     t = tick0
     while not (ranked["rt"] and ranked["bg"]):
         check(t < tick0 + 4 * cfg.rank_every * 3, "no rank cycle came due")
-        ev, tw = host_tick(hose_stack(jax.random.fold_in(key, 10_000 + t),
-                                      v_hi, v_lo, sz, 1))
+        ev, tw = stream.gen_tick(t)
         s0 = time.perf_counter()
         res = svc.step(ev, tw, log_append=writer.append)
         jax.block_until_ready(svc.rt.state)
@@ -333,7 +287,7 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
         t += 1
     writer.close()
     print(f"[serve] {len(t_live)} live ticks of {sz.batch} events + "
-          f"{sz.tweets} tweets: step wall s min/median/max = "
+          f"{sz.tweets} tweets: step host wall s min/median/max = "
           f"{min(t_live):.3f}/{float(np.median(t_live)):.3f}/"
           f"{max(t_live):.3f} (incl. first-call compiles and rank ticks)",
           flush=True)
@@ -348,11 +302,13 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
     s2 = time.perf_counter()
     packed = pack_suggestions(sugg)
     s3 = time.perf_counter()
-    print(f"[serve] rt rank cycle {s1 - s0:.3f}s (device, "
-          f"{int(table.n_rows)} rows, overflow {int(table.n_overflow)}); "
+    print(f"[serve] rt rank cycle {s1 - s0:.3f}s host wall "
+          f"({int(table.n_rows)} rows, overflow {int(table.n_overflow)}); "
           f"suggestions_to_host {s2 - s1:.3f}s ({len(sugg)} sources); "
           f"pack_suggestions {s3 - s2:.3f}s", flush=True)
-    del table, packed
+    del packed
+    phase_parity(cfg, plan, st, table)
+    del table
 
     # snapshot both engines (checkpoint + log offset)
     s0 = time.perf_counter()
@@ -364,22 +320,21 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
           flush=True)
 
     # the read path: frontends poll the persisted tables, ServerSet routes
-    tok = NGramTokenizer()
     s0 = time.perf_counter()
-    frontends = [SuggestFrontend(rt_dir, bg_dir, tok, log_dir=log_dir)
+    frontends = [SuggestFrontend(rt_dir, bg_dir, stream.tok, log_dir=log_dir)
                  for _ in range(2)]
     for f in frontends:
         f.poll()
     s1 = time.perf_counter()
-    idx = [i for i in range(sz.vocab) if int(fps[i]) in svc.suggestions]
-    check(len(idx) >= sz.n_requests,
-          f"only {len(idx)} queries have suggestions")
-    tok.fp_to_text.update(zip((int(f) for f in fps), (str(s) for s in texts)))
+    # the most popular queries with suggestions (vocab is in Zipf order)
+    queries = [q for q, f in zip(stream.vocab, stream.fps)
+               if int(f) in svc.suggestions][:sz.n_requests]
+    check(len(queries) == sz.n_requests,
+          f"only {len(queries)} queries have suggestions")
     servers = ServerSet(frontends)
-    answers = {str(texts[i]): servers.request(str(texts[i]), k=5)
-               for i in idx[:sz.n_requests]}
+    answers = {q: servers.request(q, k=5) for q in queries}
     empty = [q for q, a in answers.items() if not a]
-    q0 = next(iter(answers))
+    q0 = queries[0]
     print(f"[serve] {len(answers)} ServerSet requests, {len(empty)} empty; "
           f"frontend poll {s1 - s0:.1f}s for 2 replicas; "
           f"related({q0!r}) = {answers[q0][:3]}", flush=True)
@@ -387,6 +342,71 @@ def phase_load_serve(sz: Sizes, plan, seed: int, work: str, d0):
     stats = d0.memory_stats() or {}
     print(f"[serve] peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
           f"bytes_limit={stats.get('bytes_limit')}", flush=True)
+
+
+def phase_parity(cfg, plan, st, table):
+    """At deployment fill, where the reference engine is too slow to run:
+    the rank cycle under the plan with every kernel/jnp choice swapped
+    must give ``table`` (the tuned plan's) within the tests' tolerance,
+    and the decay sweep's kernel and jnp paths the same store, bit for
+    bit (both multiply by one scalar factor and compare to one
+    threshold)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import ranking
+    from repro.core.decay import sweep_decay_prune
+    from repro.core.hashing import join_fp
+    from repro.core.plan import JNP, KERNEL
+
+    ops = ("score_gate", "bucket_topk", "decay_prune")
+    swap = {KERNEL: JNP, JNP: KERNEL}
+    other = dataclasses.replace(
+        plan, **{op: swap[getattr(plan, op)] for op in ops})
+    s0 = time.perf_counter()
+    t2 = ranking.ranking_cycle(st.cooc, st.qstore,
+                               dataclasses.replace(cfg.rank, plan=other))
+    jax.block_until_ready(t2)
+    dt = time.perf_counter() - s0
+    a = {k: np.asarray(v) for k, v in table._asdict().items()}
+    b = {k: np.asarray(v) for k, v in t2._asdict().items()}
+    del t2
+    for k in ("n_rows", "n_overflow", "src_hi", "src_lo"):
+        check(np.array_equal(a[k], b[k]), f"swapped plan: {k} differs")
+    same = ((a["dst_hi"] == b["dst_hi"]) & (a["dst_lo"] == b["dst_lo"])
+            & (a["score"] == b["score"])).all(1)
+    rows = np.nonzero(~same)[0]
+
+    def topk(t):
+        fp = join_fp(t["dst_hi"][rows], t["dst_lo"][rows]).tolist()
+        sc = t["score"][rows].tolist()
+        return [[(f, x) for f, x in zip(fr, sr) if x > 0.0]
+                for fr, sr in zip(fp, sc)]
+
+    bad = sum(not topk_agrees(g, w) for g, w in zip(topk(b), topk(a)))
+    err = float(np.max(np.abs(a["score"] - b["score"])))
+    swapped = ", ".join(f"{op}={getattr(other, op)}" for op in ops[:2])
+    print(f"[parity] rank cycle, swapped plan ({swapped}) vs tuned: "
+          f"{int(a['n_rows'])} sources, {len(rows)} not "
+          f"bit-identical, {bad} top-k disagreements, max score error "
+          f"{err:.2e} ({dt:.1f}s incl. compile)", flush=True)
+    check(bad == 0, f"{bad} top-k lists differ between plans")
+
+    d_ticks = jnp.int32(cfg.decay_every)
+    kern, _, _ = sweep_decay_prune(st.cooc, d_ticks, cfg=cfg.decay,
+                                   use_kernel=True)
+    ref, _, _ = sweep_decay_prune(st.cooc, d_ticks, cfg=cfg.decay,
+                                  use_kernel=False)
+    diff = [str(p) for p, x, y in zip(
+        jax.tree_util.tree_leaves_with_path(kern),
+        jax.tree.leaves(kern), jax.tree.leaves(ref))
+        if not bool(jnp.array_equal(x, y))]
+    print(f"[parity] decay sweep, kernel vs jnp on the filled cooc store "
+          f"(live {int(jnp.sum(st.cooc.live_mask))} -> "
+          f"{int(jnp.sum(ref.live_mask))}): "
+          f"{'bit-identical' if not diff else f'differs in {diff}'}",
+          flush=True)
+    check(not diff, f"decay sweep kernel and jnp differ in {diff}")
 
 
 def phase_sharded(sz: Sizes, n_chips: int, seed: int):
@@ -432,21 +452,14 @@ def phase_sharded(sz: Sizes, n_chips: int, seed: int):
     want = ref.suggestions
     check(set(merged) == set(want),
           f"source sets differ: sharded {len(merged)} vs ref {len(want)}")
-    bad_src = {f for f in want if not topk_agrees(merged[f], want[f])}
-    split = se.split_sources(scfg, state)
-    n, _, err = compare_tables({f: merged[f] for f in want if f not in split},
-                               {f: want[f] for f in want if f not in split})
-    bad = len(bad_src - split)
+    n, bad, err = compare_tables(merged, want)
     per_shard = np.asarray(state.cooc.live_mask).reshape(n_chips, -1).sum(1)
     print(f"[sharded] {n_chips} shards x cooc {sz.cooc_capacity} "
           f"({per_dev} B state per device): {sz.correct_ticks} ticks x "
-          f"{sz.correct_batch} events, {len(want)} sources: {bad} top-k "
-          f"disagreements among the {n} held by one shard (max score error "
-          f"{err:.2e}); {len(bad_src & split)} among the {len(split)} split "
-          f"across shards by turning hot mid-stream; route drops={drops}, "
-          f"per-shard pairs="
-          f"{per_shard.tolist()} ({time.perf_counter() - t0:.1f}s)",
-          flush=True)
+          f"{sz.correct_batch} events, {n} sources: {bad} top-k "
+          f"disagreements, max score error {err:.2e}; route drops={drops}, "
+          f"per-shard pairs={per_shard.tolist()} "
+          f"({time.perf_counter() - t0:.1f}s)", flush=True)
     check(n > 0 and bad == 0, f"{bad} of {n} top-k lists disagree")
     check(drops == 0, f"{drops} routed pairs dropped")
 

@@ -1,6 +1,6 @@
 """Beyond-paper example: the SHARDED search-assistance backend over every
 device the process sees (a power of two of them) — key-sharded stores,
-all_to_all pair routing, hot-key salting, and shard-merged suggestions
+all_to_all pair routing, pair salting, and shard-merged suggestions
 (removes the paper's §4.4 memory wall).
 
   PYTHONPATH=src python examples/sharded_backend.py
@@ -29,8 +29,7 @@ def main() -> None:
     mesh = Mesh(np.array(devs[:n]), ("shard",))
     ecfg = EngineConfig(query_capacity=1 << 13, cooc_capacity=1 << 16,
                         session_capacity=1 << 13, decay_every=4, rank_every=0)
-    scfg = se.ShardedConfig(base=ecfg, n_salts=2, hot_threshold=40.0,
-                            route_capacity=2048)
+    scfg = se.ShardedConfig(base=ecfg, n_salts=2, route_capacity=2048)
     step = se.make_sharded_step(scfg, mesh)
     decay = se.make_sharded_decay(scfg, mesh)
     rank = se.make_sharded_rank(scfg, mesh)

@@ -11,13 +11,15 @@ axis and removes the memory wall:
     query-likeness checks stay local;
   * **sessions store**: sharded by session hash — pair *generation* is local
     to the session owner;
-  * **cooccurrence store**: sharded by *source-query* hash, so one ranking
-    cycle shard holds every pair of its source queries and top-k is local;
-  * **hot-key salting**: Zipf-skewed sources (the same skew that produced
-    the paper's Hadoop stragglers, §3.2) are split across ``n_salts``
-    shards via a salt on the destination hash; the frontend merges the
-    per-salt top-k lists. Hotness is decided against the replicated query
-    store at routing time (count >= hot_threshold).
+  * **cooccurrence store**: sharded by pair, owner = (hash(src) +
+    hash(dst) % n_salts) % n_shards. The salt spreads each source over up
+    to ``n_salts`` shards, so a Zipf-skewed source (the skew that produced
+    the paper's Hadoop stragglers, §3.2) cannot pile its updates onto one
+    shard. The owner is a function of the pair alone, so every pair lives
+    in exactly one shard for its whole life; each shard's top-k of a
+    source is the top-k of a disjoint part of its pairs, and the host
+    merge of the per-shard lists is the exact global top-k (scores read
+    only the pair and the replicated query-store marginals);
   * pair routing: fixed-capacity bucketization + ``all_to_all`` along the
     shard axis (overflow is dropped *and counted*, mirroring the paper's
     rate-limiting stance).
@@ -28,7 +30,6 @@ the same single-device store/ranking code runs per shard.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Dict, List, NamedTuple, Tuple
 
 import jax
@@ -43,15 +44,14 @@ from .decay import (prune_sweep, region_decay_sweep, region_prune_sweep,
 from .engine import (EngineConfig, cooc_insert_pairs, maintenance_cadence,
                      make_cooc_store, _Q_MODES)
 from .hashing import combine_fp_device, probe_hash
-from .ranking import RankConfig, SuggestionTable
+from .ranking import SuggestionTable
 from .stores import HashTable, SessionTable
 
 
 @dataclasses.dataclass(frozen=True)
 class ShardedConfig:
     base: EngineConfig
-    n_salts: int = 4
-    hot_threshold: float = 50.0     # count above which a src key is "hot"
+    n_salts: int = 4                # shards one source's pairs spread over
     route_capacity: int = 4096      # per-destination bucket capacity
 
 
@@ -109,6 +109,15 @@ def init_sharded_state(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"
                              _state_spec(cfg, axis),
                              is_leaf=lambda x: isinstance(x, P))
     return jax.jit(build, out_shardings=shardings)()
+
+
+def pair_owner(src_hi, src_lo, dst_hi, dst_lo, n_salts: int, n_shards: int):
+    """The shard that owns pair (src, dst): ``(hash(src) + hash(dst) %
+    n_salts) % n_shards`` — a function of the pair alone, so live routing
+    and resharding agree and a pair never has fragments in two shards."""
+    salt = probe_hash(dst_hi, dst_lo) % jnp.uint32(max(n_salts, 1))
+    return ((probe_hash(src_hi, src_lo) + salt)
+            % jnp.uint32(n_shards)).astype(jnp.int32)
 
 
 def _route(pairs_key_hi, pairs_key_lo, owner, payload: Dict[str, jax.Array],
@@ -184,16 +193,9 @@ def _ingest_body(cfg: ShardedConfig, n: int, axis: str):
             state.sessions, s_hi, s_lo, q_hi, q_lo, src, state.tick, mine,
             probe_rounds=base.probe_rounds)
 
-        # --- route pairs to cooccurrence owner: hash(src) (+ salt if hot) ---
-        svals, sfound, _ = stores.lookup(qstore, pairs.src_hi, pairs.src_lo,
-                                         probe_rounds=base.probe_rounds)
-        hot = sfound & (svals["count"] >= cfg.hot_threshold)
-        salt = jnp.where(
-            hot, (probe_hash(pairs.dst_hi, pairs.dst_lo)
-                  % jnp.uint32(cfg.n_salts)).astype(jnp.uint32),
-            jnp.uint32(0))
-        owner = ((probe_hash(pairs.src_hi, pairs.src_lo) + salt)
-                 % jnp.uint32(n)).astype(jnp.int32)
+        # --- route pairs to their cooccurrence owner ---
+        owner = pair_owner(pairs.src_hi, pairs.src_lo, pairs.dst_hi,
+                           pairs.dst_lo, cfg.n_salts, n)
         w_src = sw[jnp.clip(pairs.src_code, 0, len(base.source_weights) - 1)]
         w_dst = sw[jnp.clip(pairs.dst_code, 0, len(base.source_weights) - 1)]
         w_pair = jnp.sqrt(w_src * w_dst)
@@ -442,8 +444,10 @@ def restore_sharded_snapshot(cfg: ShardedConfig, mesh: Mesh, ckpt,
 
 def merge_sharded_suggestions(table: SuggestionTable, top_k: int
                               ) -> Dict[int, List[Tuple[int, float]]]:
-    """Host-side merge of per-shard suggestion tables (salted srcs appear in
-    up to n_salts shards)."""
+    """Host-side merge of per-shard suggestion tables: a source appears in
+    up to ``n_salts`` shards, each holding a disjoint part of its pairs, so
+    the best ``top_k`` of the union of its per-shard lists is its exact
+    top-k."""
     from .hashing import join_fp
     src_hi = np.asarray(table.src_hi).reshape(-1)
     src_lo = np.asarray(table.src_lo).reshape(-1)
@@ -462,8 +466,7 @@ def merge_sharded_suggestions(table: SuggestionTable, top_k: int
         d = merged.setdefault(int(src_fp[i]), {})
         for j in range(K):
             if score[i, j] > 0.0:
-                fp = int(dst_fp[i, j])
-                d[fp] = max(d.get(fp, 0.0), float(score[i, j]))
+                d[int(dst_fp[i, j])] = float(score[i, j])
     return {s: sorted(d.items(), key=lambda t: (-t[1], t[0]))[:top_k]
             for s, d in merged.items()}
 
@@ -473,12 +476,11 @@ def merge_sharded_suggestions(table: SuggestionTable, top_k: int
 #
 # Re-partitions a running ShardedState across a different shard count
 # without losing state: every live cooccurrence pair and session is
-# exported to a canonical host-side form, merged (the same (src, dst) pair
-# can legitimately live in several old shards — a source that crossed
-# hot_threshold mid-run salted its later inserts), then re-inserted into
-# freshly initialized per-shard stores under the NEW ownership rule — the
-# exact rule the live ingest path routes by, so post-reshard inserts land
-# on the rows the reshard placed. The qstore is replicated and copied
+# exported to a canonical host-side form (each pair lives in exactly one
+# old shard), then re-inserted into freshly initialized per-shard stores
+# under the NEW shard count's ``pair_owner`` — the rule the live ingest
+# path routes by, so post-reshard inserts land on the rows the reshard
+# placed. The qstore is replicated and copied
 # verbatim, which also keeps every region-directory slot id valid.
 #
 # The reshard is a pure function of the state content: two runs that
@@ -548,39 +550,9 @@ def _export_region_pairs(tab, qstore: HashTable) -> Dict[str, np.ndarray]:
     return out
 
 
-def _merge_duplicate_pairs(base: EngineConfig, e: Dict[str, np.ndarray]
-                           ) -> Dict[str, np.ndarray]:
-    """Canonical-sort and merge multi-shard duplicates of a (src, dst) pair.
-
-    Under the lazy decay policy the duplicates' (weight, last_tick)
-    encodings differ; each weight is rebased to the group's max last_tick
-    with the SAME decay formula the device reads use, so the merged entry
-    decays to the same effective value as the duplicates summed."""
-    if e["src_hi"].size == 0:
-        return e
-    order = np.lexsort((e["dst_lo"], e["dst_hi"], e["src_lo"], e["src_hi"]))
-    s = {k: v[order] for k, v in e.items()}
-    key = np.stack([s["src_hi"], s["src_lo"], s["dst_hi"], s["dst_lo"]], 1)
-    new_grp = np.any(key[1:] != key[:-1], axis=1)
-    starts = np.concatenate([[0], np.nonzero(new_grp)[0] + 1])
-    seg = np.concatenate([[0], np.cumsum(new_grp.astype(np.int64))])
-    lt_max = np.maximum.reduceat(s["last_tick"], starts)
-    w = s["weight"].astype(np.float32)
-    if base.lazy_decay:
-        dt = (lt_max[seg] - s["last_tick"]).astype(np.float32)
-        f = np.asarray(base.decay.factor(dt), np.float32)
-        w = (w * f).astype(np.float32)
-    out = {k: s[k][starts] for k in ("src_hi", "src_lo", "dst_hi", "dst_lo")}
-    out["weight"] = np.add.reduceat(w, starts).astype(np.float32)
-    out["count"] = np.add.reduceat(
-        s["count"].astype(np.float32), starts).astype(np.float32)
-    out["last_tick"] = lt_max.astype(np.int32)
-    return out
-
-
 def export_sharded_pairs(cfg: ShardedConfig, state: ShardedState
                          ) -> Dict[str, np.ndarray]:
-    """All live (src -> dst) pairs across shards, canonical order, merged."""
+    """All live (src -> dst) pairs across shards, in canonical order."""
     n = state.n_route_drop.shape[0]
     cols: Dict[str, list] = {k: [] for k in _PAIR_COLS}
     for i in range(n):
@@ -589,31 +561,9 @@ def export_sharded_pairs(cfg: ShardedConfig, state: ShardedState
              if cfg.base.region_cooc else _export_hash_pairs(tab))
         for k in _PAIR_COLS:
             cols[k].append(e[k])
-    merged = {k: np.concatenate(v) for k, v in cols.items()}
-    return _merge_duplicate_pairs(cfg.base, merged)
-
-
-def split_sources(cfg: ShardedConfig, state: ShardedState) -> set:
-    """Source fingerprints with a (src, dst) pair held by more than one
-    shard. A source that turns hot mid-stream keeps its earlier pairs in
-    its owner shard while later ones route to salted shards; each shard
-    scores its fragment alone and :func:`merge_sharded_suggestions` keeps
-    the larger score, so such a source ranks below the unsharded engine
-    until a reshard sums the fragments (``_merge_duplicate_pairs``)."""
-    from .hashing import join_fp
-    n = state.n_route_drop.shape[0]
-    src, dst = [], []
-    for i in range(n):
-        tab = _shard_view(state.cooc, i, n)
-        e = (_export_region_pairs(tab, state.qstore)
-             if cfg.base.region_cooc else _export_hash_pairs(tab))
-        src.append(join_fp(e["src_hi"], e["src_lo"]))
-        dst.append(join_fp(e["dst_hi"], e["dst_lo"]))
-    src, dst = np.concatenate(src), np.concatenate(dst)
-    order = np.lexsort((dst, src))
-    s, d = src[order], dst[order]
-    dup = (s[1:] == s[:-1]) & (d[1:] == d[:-1])
-    return {int(x) for x in s[1:][dup]}
+    e = {k: np.concatenate(v) for k, v in cols.items()}
+    order = np.lexsort((e["dst_lo"], e["dst_hi"], e["src_lo"], e["src_hi"]))
+    return {k: v[order] for k, v in e.items()}
 
 
 def export_sharded_sessions(state: ShardedState) -> Dict[str, np.ndarray]:
@@ -699,10 +649,10 @@ def reshard_sharded_state(cfg: ShardedConfig, state: ShardedState,
 
     Deterministic in the state content (no RNG, canonical ordering
     throughout); ``tick`` and the replicated qstore carry over unchanged,
-    so the new state replays the shared log from the same offset. Routing
-    hotness is re-decided against the current qstore — the same decision
-    the live ingest path would make next tick. Per-shard drop counters
-    restart at the insertion drops (old totals are returned in stats).
+    so the new state replays the shared log from the same offset. Pairs
+    are placed by ``pair_owner`` under ``new_n``, as the live ingest path
+    routes them. Per-shard drop counters restart at the insertion drops
+    (old totals are returned in stats).
     """
     base = cfg.base
     old_n = state.n_route_drop.shape[0]
@@ -718,18 +668,9 @@ def reshard_sharded_state(cfg: ShardedConfig, state: ShardedState,
     sess = export_sharded_sessions(state)
 
     # ownership under new_n — the SAME rule as the live ingest path
-    s_hi, s_lo = jnp.asarray(pairs["src_hi"]), jnp.asarray(pairs["src_lo"])
-    d_hi, d_lo = jnp.asarray(pairs["dst_hi"]), jnp.asarray(pairs["dst_lo"])
-    svals, sfound, _ = stores.lookup(state.qstore, s_hi, s_lo,
-                                     probe_rounds=base.probe_rounds)
-    hot = np.asarray(sfound) & (np.asarray(svals["count"])
-                                >= cfg.hot_threshold)
-    salt = np.where(hot,
-                    np.asarray(probe_hash(d_hi, d_lo)) % np.uint32(
-                        max(cfg.n_salts, 1)),
-                    np.uint32(0)).astype(np.uint64)
-    owner = ((np.asarray(probe_hash(s_hi, s_lo)).astype(np.uint64) + salt)
-             % new_n).astype(np.int64)
+    owner = np.asarray(pair_owner(
+        *(jnp.asarray(pairs[k]) for k in ("src_hi", "src_lo", "dst_hi",
+                                          "dst_lo")), cfg.n_salts, new_n))
     sess_owner = (np.asarray(
         probe_hash(jnp.asarray(sess["key_hi"]),
                    jnp.asarray(sess["key_lo"]))).astype(np.uint64)

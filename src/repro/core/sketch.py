@@ -7,8 +7,7 @@ coverage." A count-min sketch inverts the tradeoff: every key is tracked
 (full coverage of counts, within overestimation error) in O(d·w) memory
 independent of the key cardinality — at the cost of not being enumerable
 (it cannot drive ranking cycles alone; the engine uses it as a pre-filter
-for query-likeness and as a memory-bounded heavy-hitter detector feeding
-the hot-key salting in ``sharded_engine``).
+for query-likeness and as a memory-bounded heavy-hitter detector).
 
 Supports the same exponential decay as the exact stores (multiply the whole
 sketch — a dense elementwise op).

@@ -36,6 +36,19 @@ _WORDS = [
 ]
 
 
+def _cdf(p: np.ndarray) -> np.ndarray:
+    """Normalized CDF of ``p``, as ``Generator.choice`` builds it per call."""
+    cdf = p.cumsum()
+    cdf /= cdf[-1]
+    return cdf
+
+
+def _draw(rng: np.random.Generator, cdf: np.ndarray, size) -> np.ndarray:
+    """``rng.choice(len(cdf), size, p=p)`` with a precomputed ``_cdf(p)``:
+    the same draws and RNG consumption, without an O(vocab) pass per call."""
+    return cdf.searchsorted(rng.random(size), side="right")
+
+
 def _mix64(x: np.ndarray) -> np.ndarray:
     """splitmix64 finalizer (vectorized), output != 0."""
     x = np.asarray(x, np.uint64).copy()
@@ -115,14 +128,15 @@ class SyntheticStream:
         # Zipf base probabilities
         ranks = np.arange(1, cfg.vocab_size + 1, dtype=np.float64)
         p = ranks ** (-cfg.zipf_s)
-        self.base_p = p / p.sum()
+        base_p = p / p.sum()
+        self._base_cdf = _cdf(base_p)
         self.topic = rr.integers(0, cfg.n_topics, size=cfg.vocab_size)
         # per-topic sampling distributions
-        self._topic_p = []
+        self._topic_cdf = []
         for t in range(cfg.n_topics):
-            m = (self.topic == t).astype(np.float64) * self.base_p
+            m = (self.topic == t).astype(np.float64) * base_p
             s = m.sum()
-            self._topic_p.append(m / s if s > 0 else self.base_p)
+            self._topic_cdf.append(_cdf(m / s if s > 0 else base_p))
 
         # --- events: append their terms to the vocab space
         self.event_term_idx: List[np.ndarray] = []
@@ -229,11 +243,10 @@ class SyntheticStream:
             # vectorized-ish: group by topic
             for tpc in np.unique(bt[sticky]):
                 m = sticky & (bt == tpc)
-                picks[m] = rng.choice(self.cfg.vocab_size, size=int(m.sum()),
-                                      p=self._topic_p[tpc])
+                picks[m] = _draw(rng, self._topic_cdf[tpc], int(m.sum()))
             if (~sticky).any():
-                picks[~sticky] = rng.choice(self.cfg.vocab_size,
-                                            size=int((~sticky).sum()), p=self.base_p)
+                picks[~sticky] = _draw(rng, self._base_cdf,
+                                       int((~sticky).sum()))
             q_idx[base] = picks
 
         # typos on head queries
@@ -269,7 +282,7 @@ class SyntheticStream:
             topics = rng.integers(0, cfg.n_topics, size=int(rest.sum()))
             picks = np.empty((int(rest.sum()), W), np.int64)
             for i, tpc in enumerate(topics):
-                picks[i] = rng.choice(self.cfg.vocab_size, size=W, p=self._topic_p[tpc])
+                picks[i] = _draw(rng, self._topic_cdf[tpc], W)
             tw_idx[rest] = picks
         grams = np.zeros((T, cfg.tweet_grams), np.uint64)
         g = min(W, cfg.tweet_grams)

@@ -513,8 +513,9 @@ class ReplicaGroup:
 #  * fixed-size micro-batching (core/engine.py) — per-step work is constant,
 #    the Zipf skew that stretched the paper's reduce tasks cannot stretch a
 #    device step;
-#  * hot-key salting (core/sharded_engine.py) — heavy hitters are split
-#    across shards, bounding the max per-shard update volume;
+#  * pair salting (core/sharded_engine.py) — each source's pairs are
+#    spread over several shards, bounding a heavy hitter's per-shard
+#    update volume;
 #  * capacity-bounded routing/dispatch (sharded engine buckets, MoE
 #    capacity) — a skewed key/expert cannot inflate a neighbor's step time,
 #    overflow is dropped and counted instead of straggling.

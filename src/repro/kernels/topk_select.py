@@ -170,8 +170,9 @@ def bucket_topk(grid: jax.Array, k: int, *, interpret: bool | None = None
     Kp = ((max(k, 1) + SUBLANE - 1) // SUBLANE) * SUBLANE
     BR = min(_BUCKET_LANES, ((max(R, 1) + LANE - 1) // LANE) * LANE)
     Rp = ((R + BR - 1) // BR) * BR
-    gp = jnp.full((Lp, Rp), -jnp.inf, jnp.float32)
-    gp = gp.at[:L, :R].set(grid.astype(jnp.float32).T)
+    gp = grid.astype(jnp.float32).T
+    if (Lp, Rp) != (L, R):           # aligned grid: no padded copy
+        gp = jnp.full((Lp, Rp), -jnp.inf, jnp.float32).at[:L, :R].set(gp)
 
     spec_in = pl.BlockSpec((Lp, BR), lambda i: (0, i))
     spec_out = pl.BlockSpec((Kp, BR), lambda i: (0, i))

@@ -34,7 +34,10 @@ from ..models import api, transformer as tr
 from ..training import optimizer as optim
 from ..training.train_loop import TrainConfig, init_train_state, make_train_step
 from . import roofline as rl
-from .mesh import make_production_mesh
+from .mesh import device_peaks, make_production_mesh
+
+# the production mesh the dry-run models is made of v5e chips
+MODELED_DEVICE = "TPU v5 lite"
 
 
 def _opt_config(cfg) -> optim.AdamWConfig:
@@ -203,6 +206,7 @@ def build_cell(arch_id: str, shape_name: str, multi_pod: bool
     except Exception:
         pass
 
+    pk = device_peaks(MODELED_DEVICE)
     roof = rl.Roofline(
         arch=arch_id, shape=shape_name, mesh=mesh_name, chips=chips,
         flops_global=flops_g,
@@ -210,7 +214,8 @@ def build_cell(arch_id: str, shape_name: str, multi_pod: bool
         coll_bytes=coll,
         coll_by_kind=by_kind, coll_counts=counts,
         model_flops=api.model_flops(cfg, cell),
-        peak_flops=rl_peak(), hbm_bw=rl_hbm(), link_bw=rl_link(),
+        peak_flops=pk["bf16_flops"], hbm_bw=pk["hbm_bytes_s"],
+        link_bw=pk["ici_link_bytes_s"],
         memory_per_device=mem)
     row = roof.row()
     row["hlo_bytes_raw"] = bytes_raw         # diagnostic: pre-fusion metric
@@ -218,21 +223,6 @@ def build_cell(arch_id: str, shape_name: str, multi_pod: bool
     row["status"] = "ok"
     row["compile_s"] = round(t_compile, 1)
     return row
-
-
-def rl_peak():
-    from .mesh import PEAK_FLOPS_BF16
-    return PEAK_FLOPS_BF16
-
-
-def rl_hbm():
-    from .mesh import HBM_BW
-    return HBM_BW
-
-
-def rl_link():
-    from .mesh import ICI_BW_PER_LINK
-    return ICI_BW_PER_LINK
 
 
 def main() -> None:

@@ -23,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 # 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip.
 DEVICE_PEAKS = {
     "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_s": 819e9,
-                    "hbm_bytes": 16e9, "ici_link_bytes_s": 50e9},
+                    "ici_link_bytes_s": 50e9},
 }
 
 
@@ -37,9 +37,3 @@ def device_peaks(device_kind: str) -> dict:
                        f"{device_kind!r} (known: {sorted(DEVICE_PEAKS)})"
                        ) from None
 
-
-# The modeled v5e production mesh of the dry-run (virtual devices).
-_V5E = DEVICE_PEAKS["TPU v5 lite"]
-PEAK_FLOPS_BF16 = _V5E["bf16_flops"]       # FLOP/s
-HBM_BW = _V5E["hbm_bytes_s"]               # B/s
-ICI_BW_PER_LINK = _V5E["ici_link_bytes_s"]  # B/s per link

@@ -180,35 +180,6 @@ class Roofline:
         }
 
 
-def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
-            model_flops: float, peak=None, hbm=None, link=None) -> Roofline:
-    from .mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
-    ca = compiled.cost_analysis() or {}
-    flops_dev = float(ca.get("flops", 0.0))
-    bytes_dev = float(ca.get("bytes accessed", 0.0))
-    txt = compiled.as_text()
-    coll, by_kind, counts = collective_bytes(txt)
-    mem = None
-    try:
-        ma = compiled.memory_analysis()
-        mem = {
-            "argument_bytes": getattr(ma, "argument_size_in_bytes", None),
-            "output_bytes": getattr(ma, "output_size_in_bytes", None),
-            "temp_bytes": getattr(ma, "temp_size_in_bytes", None),
-            "peak_bytes": getattr(ma, "peak_memory_in_bytes", None),
-        }
-    except Exception:
-        pass
-    return Roofline(
-        arch=arch, shape=shape, mesh=mesh_name, chips=chips,
-        flops_global=flops_dev * chips, bytes_global=bytes_dev * chips,
-        coll_bytes=float(coll), coll_by_kind=by_kind, coll_counts=counts,
-        model_flops=model_flops,
-        peak_flops=peak or PEAK_FLOPS_BF16, hbm_bw=hbm or HBM_BW,
-        link_bw=link or ICI_BW_PER_LINK,
-        memory_per_device=mem)
-
-
 def hot_path_roofline(name: str, *, bytes_touched: float, flops: float,
                       measured_us: float, device_kind: str | None = None
                       ) -> Dict:

@@ -23,8 +23,7 @@ _SCRIPT = textwrap.dedent("""
     ecfg = EngineConfig(query_capacity=1<<12, cooc_capacity=1<<15,
                         session_capacity=1<<12, session_window=4,
                         decay_every=4, rank_every=0)
-    scfg = se.ShardedConfig(base=ecfg, n_salts=2, hot_threshold=30.0,
-                            route_capacity=1024)
+    scfg = se.ShardedConfig(base=ecfg, n_salts=2, route_capacity=1024)
     step = se.make_sharded_step(scfg, mesh)
     decay = se.make_sharded_decay(scfg, mesh)
     rank = se.make_sharded_rank(scfg, mesh)
@@ -50,8 +49,11 @@ _SCRIPT = textwrap.dedent("""
     assert set(merged) == set(ref), (len(merged), len(ref))
     n_score_ok = 0
     for f in merged:
-        ms = sorted([s for _, s in merged[f]], reverse=True)[:3]
-        rs = sorted([s for _, s in ref[f]], reverse=True)[:3]
+        # every rank of every source: each pair lives in one shard, so the
+        # merged lists are the unsharded engine's top-k
+        ms = [s for _, s in merged[f]]
+        rs = [s for _, s in ref[f]]
+        assert len(ms) == len(rs), (f, merged[f], ref[f])
         np.testing.assert_allclose(ms, rs, rtol=5e-3, atol=1e-4)
         n_score_ok += 1
     print(f"SHARDED_OK {len(merged)} keys, {n_score_ok} score-matched")
@@ -86,8 +88,7 @@ _REPLAY_SCRIPT = textwrap.dedent("""
                         session_capacity=1<<12, session_window=4,
                         decay_every=3, prune_every=5, rank_every=0,
                         decay=DecayConfig(policy="lazy"))
-    scfg = se.ShardedConfig(base=ecfg, n_salts=2, hot_threshold=30.0,
-                            route_capacity=1024)
+    scfg = se.ShardedConfig(base=ecfg, n_salts=2, route_capacity=1024)
     tick_step = se.make_sharded_tick_step(scfg, mesh)
     many = se.make_sharded_ingest_many(scfg, mesh)
     stream = SyntheticStream(StreamConfig(vocab_size=256, n_users=200,
@@ -164,8 +165,7 @@ _RESHARD_SCRIPT = textwrap.dedent("""
                         decay_every=3, prune_every=5, rank_every=0,
                         cooc_layout=LAYOUT, region_width=16,
                         decay=DecayConfig(policy="lazy"))
-    scfg = se.ShardedConfig(base=ecfg, n_salts=2, hot_threshold=30.0,
-                            route_capacity=1024)
+    scfg = se.ShardedConfig(base=ecfg, n_salts=2, route_capacity=1024)
     step2 = se.make_sharded_tick_step(scfg, mesh2)
     step4 = se.make_sharded_tick_step(scfg, mesh4)
     rank2 = se.make_sharded_rank(scfg, mesh2)
@@ -211,11 +211,10 @@ _RESHARD_SCRIPT = textwrap.dedent("""
         assert m_old, "old layout must answer throughout the window"
         # the handoff loses no queries ...
         assert set(m_new) == set(m_old), (len(m_new), len(m_old))
-        # ... or mass: resharding consolidates salted duplicates by SUM,
-        # while the live merge can only MAX over fragments - so per-query
-        # top scores may only grow across the handoff
+        # ... or mass: every pair moves whole to its new owner, so each
+        # query's top score carries across the handoff unchanged
         t_old, t_new = top1(m_old), top1(m_new)
-        assert all(t_new[f] >= t_old[f] - 1e-5 for f in t_old)
+        assert all(abs(t_new[f] - t_old[f]) <= 1e-5 for f in t_old)
         for b in batches[10:]:             # swap: serve live on 4 shards
             new = step4(new, *b)
         return new
