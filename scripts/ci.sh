@@ -36,7 +36,4 @@ if [ "${SKIP_BENCH:-0}" != "1" ]; then
     # >= 0.95x the best candidate) + the 16384-batch cliff (tuned chunking
     # must hold within 25% of the 4096 peak) -- both asserted in-bench
     python -m benchmarks.run --json results/BENCH_autotune.json autotune
-    # roofline smoke: distance-to-roofline rows for the tuned hot paths
-    # (roofline_hot:*; the dry-run cell rows need a separate dryrun pass)
-    python -m benchmarks.run --json results/BENCH_roofline.json roofline
 fi

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import ranking, stores
 from .decay import (DecayConfig, prune_sweep, region_decay_sweep,
                     region_prune_sweep, sweep_decay_prune)
@@ -237,22 +238,26 @@ def ingest_queries(
     # lazy policy: rebase-on-write so refreshing last_tick never un-decays
     dkw = dict(decay_cfg=cfg.decay, now=state.tick) if cfg.lazy_decay else {}
 
-    qstore = stores.insert_accumulate(
-        state.qstore, q_hi, q_lo,
-        {"weight": w, "count": jnp.ones((B,), jnp.float32), "last_tick": tick_vec},
-        valid, modes=_Q_MODES, probe_rounds=cfg.probe_rounds, **dkw)
+    with jax.named_scope("ingest.qstore"):
+        qstore = stores.insert_accumulate(
+            state.qstore, q_hi, q_lo,
+            {"weight": w, "count": jnp.ones((B,), jnp.float32),
+             "last_tick": tick_vec},
+            valid, modes=_Q_MODES, probe_rounds=cfg.probe_rounds, **dkw)
 
-    sessions, pairs = stores.update_sessions(
-        state.sessions, sess_hi, sess_lo, q_hi, q_lo, src, state.tick, valid,
-        probe_rounds=cfg.probe_rounds)
+    with jax.named_scope("ingest.sessions"):
+        sessions, pairs = stores.update_sessions(
+            state.sessions, sess_hi, sess_lo, q_hi, q_lo, src, state.tick,
+            valid, probe_rounds=cfg.probe_rounds)
 
-    # pair weight: geometric mean of the two interaction-source weights
-    w_src = sw[jnp.clip(pairs.src_code, 0, len(cfg.source_weights) - 1)]
-    w_dst = sw[jnp.clip(pairs.dst_code, 0, len(cfg.source_weights) - 1)]
-    w_pair = jnp.sqrt(w_src * w_dst)
-    cooc = cooc_insert_pairs(state.cooc, qstore, pairs.src_hi, pairs.src_lo,
-                             pairs.dst_hi, pairs.dst_lo, w_pair, pairs.valid,
-                             state.tick, cfg, dkw)
+    with jax.named_scope("ingest.cooc_insert"):
+        # pair weight: geometric mean of the two interaction-source weights
+        w_src = sw[jnp.clip(pairs.src_code, 0, len(cfg.source_weights) - 1)]
+        w_dst = sw[jnp.clip(pairs.dst_code, 0, len(cfg.source_weights) - 1)]
+        w_pair = jnp.sqrt(w_src * w_dst)
+        cooc = cooc_insert_pairs(state.cooc, qstore, pairs.src_hi,
+                                 pairs.src_lo, pairs.dst_hi, pairs.dst_lo,
+                                 w_pair, pairs.valid, state.tick, cfg, dkw)
 
     return EngineState(qstore, cooc, sessions, state.tick)
 
@@ -523,8 +528,10 @@ def ingest_many(state: EngineState, stack: TickStack, *, cfg: EngineConfig
                                     xs.q_hi[lo:hi], xs.q_lo[lo:hi],
                                     xs.src[lo:hi], xs.q_valid[lo:hi], cfg=cfg)
         if have_t:
-            st = ingest_tweets(st, xs.g_hi, xs.g_lo, xs.t_valid, cfg=cfg)
-        st = tick_maintenance(st, cfg)
+            with jax.named_scope("ingest.tweets"):
+                st = ingest_tweets(st, xs.g_hi, xs.g_lo, xs.t_valid, cfg=cfg)
+        with jax.named_scope("ingest.maintenance"):
+            st = tick_maintenance(st, cfg)
         return advance_tick(st), None
 
     state, _ = jax.lax.scan(body, state, stack)
@@ -534,6 +541,15 @@ def ingest_many(state: EngineState, stack: TickStack, *, cfg: EngineConfig
 # ---------------------------------------------------------------------------
 # Host orchestrator
 # ---------------------------------------------------------------------------
+
+def _sync(x):
+    """``x`` on the host: every blocking device->host read that
+    ``SearchAssistanceEngine`` makes goes through here, timed as
+    ``engine.sync`` and counted by ``engine.syncs``."""
+    with obs.span("engine.sync"):
+        obs.count("engine.syncs")
+        return jax.device_get(x)
+
 
 class SearchAssistanceEngine:
     """Host-side driver of one backend instance (paper Figure 4).
@@ -574,7 +590,7 @@ class SearchAssistanceEngine:
                 self.state, jnp.asarray(g_hi), jnp.asarray(g_lo),
                 jnp.asarray(tweets.valid), cfg=self.cfg)
 
-        tick = int(self.state.tick)
+        tick = int(_sync(self.state.tick))
         # one cadence authority for live, counters, and replay: cadence_due
         # (lazy: decay is amortized into reads/writes, only the prune-only
         # sweep remains at the longer prune cadence; session TTL eviction
@@ -585,12 +601,14 @@ class SearchAssistanceEngine:
         elif due == "prune":   # prune_cycle evicts sessions itself
             self.state, stats = prune_cycle(self.state, cfg=self.cfg)
             self.n_prune_cycles += 1
-            self.last_maintenance = {k: float(v) for k, v in stats.items()}
+            self.last_maintenance = {k: float(v)
+                                     for k, v in _sync(stats).items()}
         elif due == "decay":
             self.state, stats = decay_cycle(
                 self.state, jnp.int32(self.cfg.decay_every), cfg=self.cfg)
             self.n_decay_cycles += 1
-            self.last_maintenance = {k: float(v) for k, v in stats.items()}
+            self.last_maintenance = {k: float(v)
+                                     for k, v in _sync(stats).items()}
         if rank_due(self.cfg, tick):
             out = self.run_rank_cycle()
         self.state = advance_tick(self.state)
@@ -634,12 +652,14 @@ class SearchAssistanceEngine:
                  else ranking.ranking_cycle)
         table = cycle(self.state.cooc, self.state.qstore,
                       self.cfg.rank, **dkw)
+        with obs.span("rank.wait"):
+            jax.block_until_ready(table)
         self.suggestions = ranking.suggestions_to_host(table)
-        self.last_rank_tick = int(self.state.tick)
+        self.last_rank_tick = int(_sync(self.state.tick))
         self.n_rank_cycles += 1
         return {"tick": self.last_rank_tick,
-                "n_rows": int(table.n_rows),
-                "n_overflow": int(table.n_overflow),
+                "n_rows": int(_sync(table.n_rows)),
+                "n_overflow": int(_sync(table.n_overflow)),
                 "n_suggest": len(self.suggestions)}
 
     def step_many(self, stack: TickStack) -> None:
@@ -650,9 +670,9 @@ class SearchAssistanceEngine:
         Ranking cycles are NOT run (the caller decides when lag is low
         enough to resume them — see ``streaming/replay.py``).
         """
-        t0 = int(self.state.tick)
+        t0 = int(_sync(self.state.tick))
         self.state = ingest_many(self.state, stack, cfg=self.cfg)
-        t1 = int(self.state.tick)
+        t1 = int(_sync(self.state.tick))
         due = [cadence_due(self.cfg, t) for t in range(t0, t1)]
         self.n_prune_cycles += sum(d == "prune" for d in due)
         self.n_decay_cycles += sum(d == "decay" for d in due)
@@ -672,7 +692,7 @@ class SearchAssistanceEngine:
         only) is the manager's decision (``CheckpointManager.full_interval``);
         either way ``restore_from_snapshot`` sees the composed state.
         """
-        tick = int(self.state.tick)
+        tick = int(_sync(self.state.tick))
         meta = {"log_tick": tick, "engine": self.name,
                 "layout": self.cfg.cooc_layout}
         if self.cfg.plan is not None:

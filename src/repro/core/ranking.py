@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import stores
 from ..kernels.assoc_score import llr_g2
 from .decay import lazy_decayed
@@ -198,41 +199,48 @@ def _score_and_gate(cooc: HashTable, qstore: HashTable, cfg: RankConfig,
     c_ab = cooc.lanes["count"]
 
     dkw = dict(decay_cfg=decay_cfg, now=now) if decay_cfg is not None else {}
-    src_vals, src_found, src_slot = stores.lookup(qstore, src_hi, src_lo, **dkw)
-    dst_vals, dst_found, _ = stores.lookup(qstore, dst_hi, dst_lo, **dkw)
-    if decay_cfg is not None:
-        total_w = jnp.sum(lazy_decayed(decay_cfg, qstore.lanes["weight"],
-                                       qstore.lanes["last_tick"], now))
-    else:
-        total_w = jnp.sum(qstore.lanes["weight"])
-    total_c = jnp.sum(qstore.lanes["count"])
-
-    base_ok = live & src_found & dst_found
-    if cfg.kernel_on("score_gate"):
-        from ..kernels import ops as kops
-        score = kops.score_gate(
-            w_ab, c_ab, src_vals["weight"], dst_vals["weight"],
-            src_vals["count"], dst_vals["count"], base_ok, total_w, total_c,
-            coefs=(cfg.coef_condprob, cfg.coef_pmi, cfg.coef_llr, cfg.coef_chi2),
-            min_pair_weight=cfg.min_pair_weight,
-            min_src_weight=cfg.min_src_weight,
-            min_pair_count=cfg.min_pair_count,
-            decay_cfg=decay_cfg, last_tick=cooc.lanes["last_tick"], now=now,
-            block_rows=(cfg.plan.score_block_rows
-                        if cfg.plan is not None else None))
-        ok = score > -jnp.inf
-    else:
+    with jax.named_scope("rank.lookup_src"):
+        src_vals, src_found, src_slot = stores.lookup(qstore, src_hi,
+                                                      src_lo, **dkw)
+    with jax.named_scope("rank.lookup_dst"):
+        dst_vals, dst_found, _ = stores.lookup(qstore, dst_hi, dst_lo, **dkw)
+    with jax.named_scope("rank.score_gate"):
         if decay_cfg is not None:
-            w_ab = lazy_decayed(decay_cfg, w_ab, cooc.lanes["last_tick"], now)
-        lanes = assoc_scores_jnp(w_ab, c_ab, src_vals["weight"],
-                                 dst_vals["weight"], src_vals["count"],
-                                 dst_vals["count"], total_w, total_c)
-        score = combine_scores(cfg, *lanes)
-        ok = (base_ok
-              & (w_ab >= cfg.min_pair_weight)
-              & (c_ab >= cfg.min_pair_count)
-              & (src_vals["weight"] >= cfg.min_src_weight))
-        score = jnp.where(ok, score, -jnp.inf)
+            total_w = jnp.sum(lazy_decayed(decay_cfg, qstore.lanes["weight"],
+                                           qstore.lanes["last_tick"], now))
+        else:
+            total_w = jnp.sum(qstore.lanes["weight"])
+        total_c = jnp.sum(qstore.lanes["count"])
+
+        base_ok = live & src_found & dst_found
+        if cfg.kernel_on("score_gate"):
+            from ..kernels import ops as kops
+            score = kops.score_gate(
+                w_ab, c_ab, src_vals["weight"], dst_vals["weight"],
+                src_vals["count"], dst_vals["count"], base_ok, total_w,
+                total_c, coefs=(cfg.coef_condprob, cfg.coef_pmi, cfg.coef_llr,
+                                cfg.coef_chi2),
+                min_pair_weight=cfg.min_pair_weight,
+                min_src_weight=cfg.min_src_weight,
+                min_pair_count=cfg.min_pair_count,
+                decay_cfg=decay_cfg, last_tick=cooc.lanes["last_tick"],
+                now=now,
+                block_rows=(cfg.plan.score_block_rows
+                            if cfg.plan is not None else None))
+            ok = score > -jnp.inf
+        else:
+            if decay_cfg is not None:
+                w_ab = lazy_decayed(decay_cfg, w_ab, cooc.lanes["last_tick"],
+                                    now)
+            lanes = assoc_scores_jnp(w_ab, c_ab, src_vals["weight"],
+                                     dst_vals["weight"], src_vals["count"],
+                                     dst_vals["count"], total_w, total_c)
+            score = combine_scores(cfg, *lanes)
+            ok = (base_ok
+                  & (w_ab >= cfg.min_pair_weight)
+                  & (c_ab >= cfg.min_pair_count)
+                  & (src_vals["weight"] >= cfg.min_src_weight))
+            score = jnp.where(ok, score, -jnp.inf)
     return score, ok, src_slot, (src_hi, src_lo, dst_hi, dst_lo)
 
 
@@ -274,66 +282,72 @@ def ranking_cycle(
     # ---- sort-free stream compaction of gate-passing ROW IDS (one scatter;
     # payloads stay in place and are gathered on demand). Overflow beyond
     # the arena is cut by table position — counted, never silent. ----
-    if cfg.seg_arena_frac >= 1.0:
-        M = C
-        idx = jnp.arange(C, dtype=jnp.int32)
-        arena_spill = jnp.zeros((), jnp.int32)
-        s = jnp.where(ok, score, -jnp.inf)
-        seg = jnp.where(ok, src_slot, Q)
-    else:
-        M = min(C, max(K, int(C * cfg.seg_arena_frac)))
-        pos = jnp.cumsum(ok.astype(jnp.int32)) - 1
-        tgt = jnp.where(ok & (pos < M), pos, M)
-        idx = jnp.full((M,), C, jnp.int32).at[tgt].set(
-            jnp.arange(C, dtype=jnp.int32), mode="drop")
-        arena_spill = jnp.maximum(jnp.sum(ok.astype(jnp.int32)) - M, 0)
-        filled = idx < C
-        safe_idx = jnp.clip(idx, 0, C - 1)
-        s = jnp.where(filled, score[safe_idx], -jnp.inf)
-        seg = jnp.where(filled, src_slot[safe_idx], Q)
+    with jax.named_scope("rank.compact"):
+        if cfg.seg_arena_frac >= 1.0:
+            M = C
+            idx = jnp.arange(C, dtype=jnp.int32)
+            arena_spill = jnp.zeros((), jnp.int32)
+            s = jnp.where(ok, score, -jnp.inf)
+            seg = jnp.where(ok, src_slot, Q)
+        else:
+            M = min(C, max(K, int(C * cfg.seg_arena_frac)))
+            pos = jnp.cumsum(ok.astype(jnp.int32)) - 1
+            tgt = jnp.where(ok & (pos < M), pos, M)
+            idx = jnp.full((M,), C, jnp.int32).at[tgt].set(
+                jnp.arange(C, dtype=jnp.int32), mode="drop")
+            arena_spill = jnp.maximum(jnp.sum(ok.astype(jnp.int32)) - M, 0)
+            filled = idx < C
+            safe_idx = jnp.clip(idx, 0, C - 1)
+            s = jnp.where(filled, score[safe_idx], -jnp.inf)
+            seg = jnp.where(filled, src_slot[safe_idx], Q)
 
     # ---- ONE grouping sort on two u32 keys: bucket id (the empty/gated
     # sentinel Q sorts last), then the inverted exact score, so each
     # bucket's rows are contiguous, best-first. ----
-    sseg, _, sidx = jax.lax.sort(
-        (seg.astype(jnp.uint32), ~_sortable_f32(s), idx), num_keys=2,
-        is_stable=True)
-    valid_row = sseg < Q
-    is_new = jnp.concatenate(
-        [jnp.ones((1,), bool), sseg[1:] != sseg[:-1]]) & valid_row
-    run_id = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    ar = jnp.arange(M, dtype=jnp.int32)
-    pos_in_run = ar - jax.lax.cummax(jnp.where(is_new, ar, 0))
+    with jax.named_scope("rank.group_sort"):
+        sseg, _, sidx = jax.lax.sort(
+            (seg.astype(jnp.uint32), ~_sortable_f32(s), idx), num_keys=2,
+            is_stable=True)
+        valid_row = sseg < Q
+        is_new = jnp.concatenate(
+            [jnp.ones((1,), bool), sseg[1:] != sseg[:-1]]) & valid_row
+        run_id = jnp.cumsum(is_new.astype(jnp.int32)) - 1
+        ar = jnp.arange(M, dtype=jnp.int32)
+        pos_in_run = ar - jax.lax.cummax(jnp.where(is_new, ar, 0))
 
     # ---- dense [R, L] bucket grid, built by gathers only. run_id is
     # non-decreasing, so run starts come from a vectorized binary search. --
-    R = min(Q, M, max(cfg.source_cap(Q), 1))
-    run_start = jnp.searchsorted(run_id, jnp.arange(R + 1, dtype=jnp.int32)
-                                 ).astype(jnp.int32)
-    cell = run_start[:R, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
-    in_run = cell < run_start[1:, None]   # next run's start bounds this run
-    cell_c = jnp.clip(cell, 0, M - 1)
-    # sorted position -> original table row (sidx carries the permuted row
-    # ids; C is the empty-arena-slot sentinel) -> exact score.
-    cell_orig = sidx[cell_c]
-    grid = jnp.where(in_run & (cell_orig < C),
-                     score[jnp.clip(cell_orig, 0, C - 1)], -jnp.inf)
-    if cfg.kernel_on("bucket_topk"):
-        from ..kernels import ops as kops
-        vals, args = kops.bucket_topk(grid, K)
-    else:
-        vals, args = jax.lax.top_k(grid, K)
-    good = vals > -jnp.inf
+    with jax.named_scope("rank.grid"):
+        R = min(Q, M, max(cfg.source_cap(Q), 1))
+        run_start = jnp.searchsorted(run_id, jnp.arange(R + 1, dtype=jnp.int32)
+                                     ).astype(jnp.int32)
+        cell = run_start[:R, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
+        in_run = cell < run_start[1:, None]   # the next run's start bounds it
+        cell_c = jnp.clip(cell, 0, M - 1)
+        # sorted position -> original table row (sidx carries the permuted row
+        # ids; C is the empty-arena-slot sentinel) -> exact score.
+        cell_orig = sidx[cell_c]
+        grid = jnp.where(in_run & (cell_orig < C),
+                         score[jnp.clip(cell_orig, 0, C - 1)], -jnp.inf)
 
-    win_sorted = jnp.clip(run_start[:R, None] + args, 0, M - 1)
-    win_orig = jnp.clip(sidx[win_sorted], 0, C - 1)
-    out_dst_hi = jnp.where(good, dst_hi[win_orig], jnp.uint32(0))
-    out_dst_lo = jnp.where(good, dst_lo[win_orig], jnp.uint32(0))
-    out_score = jnp.where(good, vals, 0.0)
-    has_run = run_start[:R] < M
-    head_orig = jnp.clip(sidx[jnp.clip(run_start[:R], 0, M - 1)], 0, C - 1)
-    out_src_hi = jnp.where(has_run, src_hi[head_orig], jnp.uint32(0))
-    out_src_lo = jnp.where(has_run, src_lo[head_orig], jnp.uint32(0))
+    with jax.named_scope("rank.topk"):
+        if cfg.kernel_on("bucket_topk"):
+            from ..kernels import ops as kops
+            vals, args = kops.bucket_topk(grid, K)
+        else:
+            vals, args = jax.lax.top_k(grid, K)
+
+    with jax.named_scope("rank.gather_out"):
+        good = vals > -jnp.inf
+        win_sorted = jnp.clip(run_start[:R, None] + args, 0, M - 1)
+        win_orig = jnp.clip(sidx[win_sorted], 0, C - 1)
+        out_dst_hi = jnp.where(good, dst_hi[win_orig], jnp.uint32(0))
+        out_dst_lo = jnp.where(good, dst_lo[win_orig], jnp.uint32(0))
+        out_score = jnp.where(good, vals, 0.0)
+        has_run = run_start[:R] < M
+        head_orig = jnp.clip(sidx[jnp.clip(run_start[:R], 0, M - 1)], 0, C - 1)
+        out_src_hi = jnp.where(has_run, src_hi[head_orig], jnp.uint32(0))
+        out_src_lo = jnp.where(has_run, src_lo[head_orig], jnp.uint32(0))
 
     n_rows = jnp.sum(has_run.astype(jnp.int32))   # rows actually emitted
     select_spill = jnp.sum(
@@ -563,16 +577,19 @@ def suggestions_to_host(table: SuggestionTable) -> dict:
     rather than relying on every filler entry carrying score 0.
     """
     from .hashing import join_fp
-    src_hi = np.asarray(table.src_hi)
-    src_lo = np.asarray(table.src_lo)
-    mask = ((src_hi != 0) | (src_lo != 0)) \
-        & ~((src_hi == 0xFFFFFFFF) & (src_lo == 0xFFFFFFFF))
-    out = {}
-    dst_fp = join_fp(np.asarray(table.dst_hi), np.asarray(table.dst_lo))
-    score = np.asarray(table.score)
-    for i in np.nonzero(mask)[0]:
-        fp = int(join_fp(src_hi[i], src_lo[i]))
-        row = [(int(d), float(s)) for d, s in zip(dst_fp[i], score[i]) if s > 0.0]
-        if row:
-            out[fp] = row
+    with obs.span("rank.to_host"):
+        src_hi = np.asarray(table.src_hi)
+        src_lo = np.asarray(table.src_lo)
+        mask = ((src_hi != 0) | (src_lo != 0)) \
+            & ~((src_hi == 0xFFFFFFFF) & (src_lo == 0xFFFFFFFF))
+        out = {}
+        dst_fp = join_fp(np.asarray(table.dst_hi), np.asarray(table.dst_lo))
+        score = np.asarray(table.score)
+        for i in np.nonzero(mask)[0]:
+            fp = int(join_fp(src_hi[i], src_lo[i]))
+            row = [(int(d), float(s)) for d, s in zip(dst_fp[i], score[i])
+                   if s > 0.0]
+            if row:
+                out[fp] = row
+        obs.count("rank.rows_exported", len(out))
     return out

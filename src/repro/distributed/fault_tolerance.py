@@ -78,6 +78,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 import jax
 import numpy as np
 
+from .. import obs
 from ..core.stores import apply_row_delta, diff_leading_rows
 
 
@@ -165,77 +166,79 @@ class CheckpointManager:
         chain format. The decision is internal: callers keep calling
         ``save`` and the manifest records what was written.
         """
-        leaves, treedef = jax.tree.flatten(tree)
-        np_leaves = [np.asarray(x) for x in leaves]
-        kind, base_step = "full", None
-        if (self.full_interval > 1 and self._shadow is not None
-                and self._shadow_step is not None
-                and step > self._shadow_step
-                and self._since_full < self.full_interval - 1
-                and len(np_leaves) == len(self._shadow)
-                and all(a.shape == b.shape and a.dtype == b.dtype
-                        for a, b in zip(np_leaves, self._shadow))):
-            kind, base_step = "delta", self._shadow_step
-        tmp = tempfile.mkdtemp(dir=self.dir, prefix=".tmp_")
-        try:
-            arrays: Dict[str, np.ndarray] = {}
-            dtypes: Dict[str, str] = {}
-            for i, a in enumerate(np_leaves):
-                if kind == "delta" and a.ndim >= 1:
-                    idx = diff_leading_rows(self._shadow[i], a)
-                    val, raw = _raw_view(a[idx])
-                    if raw is not None:
-                        dtypes[f"leaf_{i}"] = raw
-                    arrays[f"leaf_{i}_idx"] = idx
-                    arrays[f"leaf_{i}_val"] = val
-                else:   # full leaf; 0-d leaves are always written whole
-                    whole, raw = _raw_view(a)
-                    if raw is not None:
-                        dtypes[f"leaf_{i}"] = raw
-                    arrays[f"leaf_{i}"] = whole
-            blob, cinfo = _codec().encode_payload(arrays, codec=self.codec,
-                                                  fp_lanes=())
-            with open(os.path.join(tmp, "arrays.npz"), "wb") as f:
-                f.write(blob)
-                f.flush()
-                os.fsync(f.fileno())
-            manifest = {
-                "step": step,
-                "kind": kind,
-                "base_step": base_step,
-                "n_leaves": len(leaves),
-                "raw_dtypes": dtypes,
-                "sha256": hashlib.sha256(blob).hexdigest(),
-                "nbytes": len(blob),
-                "codec": cinfo["codec"],
-                "raw_sha256": cinfo.get("raw_sha256"),
-                "raw_nbytes": cinfo.get("raw_nbytes"),
-                "time": time.time(),
-                "meta": meta or {},
-            }
-            with open(os.path.join(tmp, "MANIFEST.json"), "w") as f:
-                json.dump(manifest, f)
-                f.flush()
-                os.fsync(f.fileno())
-            final = self._step_dir(step)
-            if os.path.exists(final):
-                shutil.rmtree(final)
-            os.rename(tmp, final)
-        except Exception:
-            shutil.rmtree(tmp, ignore_errors=True)
-            raise
-        # the shadow must hold the as-saved CONTENT: np.asarray of a numpy
-        # leaf aliases the caller's live buffer (an in-place mutation
-        # before the next save would diff the array against itself and
-        # silently record an empty delta) — copy those; jax buffers are
-        # immutable and safe to hold by reference.
-        self._shadow = [a if isinstance(x, jax.Array) else np.array(a)
-                        for x, a in zip(leaves, np_leaves)]
-        self._shadow_step = step
-        self._since_full = 0 if kind == "full" else self._since_full + 1
-        self.last_save_kind, self.last_save_bytes = kind, len(blob)
-        self._gc()
-        return self._step_dir(step)
+        with obs.span("persist.save"):
+            leaves, treedef = jax.tree.flatten(tree)
+            np_leaves = [np.asarray(x) for x in leaves]
+            kind, base_step = "full", None
+            if (self.full_interval > 1 and self._shadow is not None
+                    and self._shadow_step is not None
+                    and step > self._shadow_step
+                    and self._since_full < self.full_interval - 1
+                    and len(np_leaves) == len(self._shadow)
+                    and all(a.shape == b.shape and a.dtype == b.dtype
+                            for a, b in zip(np_leaves, self._shadow))):
+                kind, base_step = "delta", self._shadow_step
+            tmp = tempfile.mkdtemp(dir=self.dir, prefix=".tmp_")
+            try:
+                arrays: Dict[str, np.ndarray] = {}
+                dtypes: Dict[str, str] = {}
+                for i, a in enumerate(np_leaves):
+                    if kind == "delta" and a.ndim >= 1:
+                        idx = diff_leading_rows(self._shadow[i], a)
+                        val, raw = _raw_view(a[idx])
+                        if raw is not None:
+                            dtypes[f"leaf_{i}"] = raw
+                        arrays[f"leaf_{i}_idx"] = idx
+                        arrays[f"leaf_{i}_val"] = val
+                    else:   # full leaf; 0-d leaves are always written whole
+                        whole, raw = _raw_view(a)
+                        if raw is not None:
+                            dtypes[f"leaf_{i}"] = raw
+                        arrays[f"leaf_{i}"] = whole
+                blob, cinfo = _codec().encode_payload(
+                    arrays, codec=self.codec, fp_lanes=())
+                with open(os.path.join(tmp, "arrays.npz"), "wb") as f:
+                    f.write(blob)
+                    f.flush()
+                    os.fsync(f.fileno())
+                manifest = {
+                    "step": step,
+                    "kind": kind,
+                    "base_step": base_step,
+                    "n_leaves": len(leaves),
+                    "raw_dtypes": dtypes,
+                    "sha256": hashlib.sha256(blob).hexdigest(),
+                    "nbytes": len(blob),
+                    "codec": cinfo["codec"],
+                    "raw_sha256": cinfo.get("raw_sha256"),
+                    "raw_nbytes": cinfo.get("raw_nbytes"),
+                    "time": time.time(),
+                    "meta": meta or {},
+                }
+                with open(os.path.join(tmp, "MANIFEST.json"), "w") as f:
+                    json.dump(manifest, f)
+                    f.flush()
+                    os.fsync(f.fileno())
+                final = self._step_dir(step)
+                if os.path.exists(final):
+                    shutil.rmtree(final)
+                os.rename(tmp, final)
+            except Exception:
+                shutil.rmtree(tmp, ignore_errors=True)
+                raise
+            # the shadow must hold the as-saved CONTENT: np.asarray of a
+            # numpy leaf aliases the caller's live buffer (an in-place
+            # mutation before the next save would diff the array against
+            # itself and silently record an empty delta) — copy those; jax
+            # buffers are immutable and safe to hold by reference.
+            self._shadow = [a if isinstance(x, jax.Array) else np.array(a)
+                            for x, a in zip(leaves, np_leaves)]
+            self._shadow_step = step
+            self._since_full = 0 if kind == "full" else self._since_full + 1
+            self.last_save_kind, self.last_save_bytes = kind, len(blob)
+            self._gc()
+            obs.count("persist.bytes", len(blob))
+            return self._step_dir(step)
 
     # -- chain-walk loading --
     def _verified_arrays(self, step: int, manifest: Dict

@@ -57,7 +57,7 @@ from ..core.plan import (HOT_PATH_OPS, JNP, KERNEL, TunedPlan,
 from ..kernels import kernels_native
 
 __all__ = ["tune", "tune_engine_config", "measure_plan", "cache_dir",
-           "cache_path", "hot_path_traffic", "TunedPlan", "shape_class"]
+           "cache_path", "TunedPlan", "shape_class"]
 
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 # bumped whenever a candidate's code changes, so no plan measured on old
@@ -414,37 +414,3 @@ def tune_engine_config(cfg, **kw):
     (``EngineConfig.plan``; its ``__post_init__`` forwards it to the
     ranking config)."""
     return dataclasses.replace(cfg, plan=tune(cfg, **kw))
-
-
-# ---------------------------------------------------------------------------
-# roofline hooks: per-op HBM traffic models for the tuned hot paths
-# ---------------------------------------------------------------------------
-
-
-def hot_path_traffic(cfg) -> Dict[str, Dict[str, float]]:
-    """Analytic bytes/flops per hot-path invocation, for
-    ``roofline.hot_path_roofline`` rows (bytes dominate every one of these
-    ops — they are table sweeps; flops are a lanes-linear estimate)."""
-    C = float(cfg.cooc_capacity)
-    rk = cfg.rank
-    out: Dict[str, Dict[str, float]] = {}
-    if not cfg.region_cooc:
-        # 7 f32 input lanes read + 1 f32 score lane written
-        out["score_gate"] = {"bytes": 8 * 4 * C, "flops": 60 * C}
-        M = min(C, max(rk.top_k, int(C * min(rk.seg_arena_frac, 1.0))))
-        R = min(cfg.query_capacity, M)
-        L = max(rk.bucket_rows, rk.top_k)
-        out["bucket_topk"] = {
-            "bytes": 4.0 * R * L + 8.0 * R * rk.top_k,
-            "flops": 3.0 * R * L * rk.top_k}
-    else:
-        W = float(cfg.region_w)
-        out["region_rank"] = {
-            "bytes": 8 * 4 * C + 8.0 * (C / W) * min(rk.top_k, int(W)),
-            "flops": 60 * C}
-        B = float(min(4096, max(256, cfg.ingest_quantum or 1024)))
-        out["chain_find"] = {"bytes": B * 2 * (2 * 4 * W + 4),
-                             "flops": B * 2 * 3 * W}
-    # keys (2 u32) + 3 lanes read and written
-    out["decay_prune"] = {"bytes": 2 * (2 + 3) * 4 * C, "flops": 6 * C}
-    return out

@@ -178,41 +178,3 @@ class Roofline:
             "coll_counts": {k: v for k, v in self.coll_counts.items() if v},
             "memory_per_device": self.memory_per_device,
         }
-
-
-def hot_path_roofline(name: str, *, bytes_touched: float, flops: float,
-                      measured_us: float, device_kind: str | None = None
-                      ) -> Dict:
-    """Distance-to-roofline row for ONE measured hot-path op.
-
-    The tuned engine ops (``autotune.hot_path_traffic`` supplies the
-    analytic bytes/flops) are table sweeps: the hardware ceiling for each
-    is ``max(bytes/hbm_bw, flops/peak)`` — no collectives, one device.
-    ``roofline_fraction`` is ceiling-time over measured-time (1.0 = the op
-    runs as fast as the memory system allows). Mirrors
-    :meth:`Roofline.row` field names so both row kinds land in the same
-    reports. The peaks are those of ``device_kind`` (default: the first
-    device's), from ``mesh.DEVICE_PEAKS``; a device with no published
-    peaks raises.
-    """
-    import jax
-
-    from .mesh import device_peaks
-    if device_kind is None:
-        device_kind = jax.devices()[0].device_kind
-    pk = device_peaks(device_kind)
-    peak, hbm = pk["bf16_flops"], pk["hbm_bytes_s"]
-    t_mem = bytes_touched / hbm
-    t_comp = flops / peak
-    t_ceiling = max(t_mem, t_comp, 1e-30)
-    t_meas = measured_us * 1e-6
-    return {
-        "op": name,
-        "bytes_touched": bytes_touched,
-        "model_flops": flops,
-        "t_compute_s": t_comp,
-        "t_memory_s": t_mem,
-        "t_measured_s": t_meas,
-        "bottleneck": "memory" if t_mem >= t_comp else "compute",
-        "roofline_fraction": t_ceiling / max(t_meas, 1e-30),
-    }

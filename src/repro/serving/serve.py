@@ -32,6 +32,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.background import interpolate
 from ..core.hashing import fingerprint
 from ..data.tokenizer import NGramTokenizer
@@ -40,29 +41,31 @@ from ..distributed.fault_tolerance import CheckpointManager
 
 def pack_suggestions(sugg: Dict[int, List[Tuple[int, float]]]) -> Dict[str, np.ndarray]:
     """Suggestion dict -> flat arrays for checkpointing."""
-    srcs, dsts, scores, offs = [], [], [], [0]
-    for s, lst in sugg.items():
-        srcs.append(s)
-        for d, sc in lst:
-            dsts.append(d)
-            scores.append(sc)
-        offs.append(len(dsts))
-    return {"src": np.asarray(srcs, np.uint64),
-            "dst": np.asarray(dsts, np.uint64),
-            "score": np.asarray(scores, np.float64),
-            "offsets": np.asarray(offs, np.int64)}
+    with obs.span("persist.pack"):
+        srcs, dsts, scores, offs = [], [], [], [0]
+        for s, lst in sugg.items():
+            srcs.append(s)
+            for d, sc in lst:
+                dsts.append(d)
+                scores.append(sc)
+            offs.append(len(dsts))
+        return {"src": np.asarray(srcs, np.uint64),
+                "dst": np.asarray(dsts, np.uint64),
+                "score": np.asarray(scores, np.float64),
+                "offsets": np.asarray(offs, np.int64)}
 
 
 def unpack_suggestions(arrays) -> Dict[int, List[Tuple[int, float]]]:
-    out: Dict[int, List[Tuple[int, float]]] = {}
-    src = arrays["src"]
-    offs = arrays["offsets"]
-    for i, s in enumerate(src):
-        lo, hi = int(offs[i]), int(offs[i + 1])
-        out[int(s)] = [(int(d), float(sc))
-                       for d, sc in zip(arrays["dst"][lo:hi],
-                                        arrays["score"][lo:hi])]
-    return out
+    with obs.span("poll.unpack"):
+        out: Dict[int, List[Tuple[int, float]]] = {}
+        src = arrays["src"]
+        offs = arrays["offsets"]
+        for i, s in enumerate(src):
+            lo, hi = int(offs[i]), int(offs[i + 1])
+            out[int(s)] = [(int(d), float(sc))
+                           for d, sc in zip(arrays["dst"][lo:hi],
+                                            arrays["score"][lo:hi])]
+        return out
 
 
 class SuggestFrontend:
@@ -112,13 +115,15 @@ class SuggestFrontend:
             arrs = self.spell_ckpt.restore_host(steps[2])
             self._spell = {int(a): (int(b), float(d)) for a, b, d in
                            zip(arrs["leaf_0"], arrs["leaf_1"], arrs["leaf_2"])}
-        self._cache = interpolate(self._rt, self._bg, self.alpha)
+        with obs.span("poll.blend"):
+            self._cache = interpolate(self._rt, self._bg, self.alpha)
         self._loaded_steps = steps
         return True
 
     @staticmethod
     def _load(ckpt: CheckpointManager, step: int) -> Dict:
-        arrs = ckpt.restore_host(step)
+        with obs.span("poll.read"):
+            arrs = ckpt.restore_host(step)
         # saved via pack_suggestions tree order: dst, offsets, score, src
         named = dict(zip(["dst", "offsets", "score", "src"],
                          [arrs[f"leaf_{i}"] for i in range(4)]))

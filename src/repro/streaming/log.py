@@ -80,6 +80,7 @@ from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..data.stream import QueryEvents, TweetBatch
 from .codec import DEFAULT_CODEC, decode_payload, encode_payload
 
@@ -604,9 +605,11 @@ class FirehoseLogReader:
 
     # -- reads --
     def _load_segment(self, seg: Segment) -> LogChunk:
-        blob = self._read_bytes_retry(os.path.join(self.dir, seg.file))
-        payload, _info = decode_payload(blob)
-        return LogChunk(**{k: payload[k] for k in _LANES})
+        with obs.span("log.read"):
+            blob = self._read_bytes_retry(os.path.join(self.dir, seg.file))
+            obs.count("log.bytes", len(blob))
+            payload, _info = decode_payload(blob)
+            return LogChunk(**{k: payload[k] for k in _LANES})
 
     def read_chunks(self, from_tick: int, chunk_ticks: Optional[int] = None,
                     upto_tick: Optional[int] = None) -> Iterator[LogChunk]:

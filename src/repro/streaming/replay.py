@@ -50,6 +50,7 @@ from typing import Dict, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.engine import (EngineConfig, SearchAssistanceEngine, TickStack)
 from ..core.hashing import split_fp
 from ..distributed.fault_tolerance import CheckpointManager
@@ -67,16 +68,17 @@ class ReplayConfig:
 
 def chunk_to_stack(chunk: LogChunk) -> TickStack:
     """Host log chunk -> device TickStack (u64 fps split into u32 lanes)."""
-    s_hi, s_lo = split_fp(chunk.sess_fp)
-    q_hi, q_lo = split_fp(chunk.q_fp)
-    g_hi, g_lo = split_fp(chunk.grams)
-    return TickStack(
-        sess_hi=jnp.asarray(s_hi), sess_lo=jnp.asarray(s_lo),
-        q_hi=jnp.asarray(q_hi), q_lo=jnp.asarray(q_lo),
-        src=jnp.asarray(chunk.src, jnp.int32),
-        q_valid=jnp.asarray(chunk.q_valid),
-        g_hi=jnp.asarray(g_hi), g_lo=jnp.asarray(g_lo),
-        t_valid=jnp.asarray(chunk.t_valid))
+    with obs.span("replay.stack"):
+        s_hi, s_lo = split_fp(chunk.sess_fp)
+        q_hi, q_lo = split_fp(chunk.q_fp)
+        g_hi, g_lo = split_fp(chunk.grams)
+        return TickStack(
+            sess_hi=jnp.asarray(s_hi), sess_lo=jnp.asarray(s_lo),
+            q_hi=jnp.asarray(q_hi), q_lo=jnp.asarray(q_lo),
+            src=jnp.asarray(chunk.src, jnp.int32),
+            q_valid=jnp.asarray(chunk.q_valid),
+            g_hi=jnp.asarray(g_hi), g_lo=jnp.asarray(g_lo),
+            t_valid=jnp.asarray(chunk.t_valid))
 
 
 class CatchUpController:

@@ -232,18 +232,3 @@ def test_resolve_interpret():
     assert resolve_interpret(None) == (not native)
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
-
-
-def test_roofline_peaks_keyed_by_device_kind():
-    """Roofline rows use the named device's published peaks; a device kind
-    with none raises instead of borrowing another chip's."""
-    from repro.launch.mesh import DEVICE_PEAKS
-    from repro.launch.roofline import hot_path_roofline
-    pk = DEVICE_PEAKS["TPU v5 lite"]
-    row = hot_path_roofline("sweep", bytes_touched=pk["hbm_bytes_s"] * 1e-3,
-                            flops=0.0, measured_us=2000.0,
-                            device_kind="TPU v5 lite")
-    assert row["roofline_fraction"] == pytest.approx(0.5)
-    with pytest.raises(KeyError, match="no published peaks"):
-        hot_path_roofline("sweep", bytes_touched=1.0, flops=1.0,
-                          measured_us=1.0, device_kind="cpu")
