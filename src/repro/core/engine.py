@@ -657,9 +657,13 @@ class SearchAssistanceEngine:
         self.suggestions = ranking.suggestions_to_host(table)
         self.last_rank_tick = int(_sync(self.state.tick))
         self.n_rank_cycles += 1
-        return {"tick": self.last_rank_tick,
-                "n_rows": int(_sync(table.n_rows)),
-                "n_overflow": int(_sync(table.n_overflow)),
+        n_rows, n_overflow, lookups = _sync(
+            (table.n_rows, table.n_overflow, table.lookup_stats))
+        for full_rounds, tail_rows in lookups:
+            obs.count("rank.lookup_full_rounds", int(full_rounds))
+            obs.count("rank.lookup_tail_rows", int(tail_rows))
+        return {"tick": self.last_rank_tick, "n_rows": int(n_rows),
+                "n_overflow": int(n_overflow),
                 "n_suggest": len(self.suggestions)}
 
     def step_many(self, stack: TickStack) -> None:

@@ -181,6 +181,9 @@ class SuggestionTable(NamedTuple):
     score: jax.Array     # f32[M, K]  (0 => empty slot)
     n_rows: jax.Array    # i32[]
     n_overflow: jax.Array  # i32[] — gate-passing rows beyond the compaction cap
+    # i32[n_lookups, 2] — per qstore lookup of the cycle: probe rounds run
+    # over the whole batch, rows finished in the tail arena
+    lookup_stats: Optional[jax.Array] = None
 
 
 def _score_and_gate(cooc: HashTable, qstore: HashTable, cfg: RankConfig,
@@ -188,7 +191,8 @@ def _score_and_gate(cooc: HashTable, qstore: HashTable, cfg: RankConfig,
     """Shared ranking prologue: marginals lookup, association scoring and
     evidence gating — with the read-time decayed view under the lazy policy.
 
-    Returns (score [-inf where gated], ok mask, src qstore slot, key lanes).
+    Returns (score [-inf where gated], ok mask, src qstore slot, key lanes,
+    the two lookups' stats).
     """
     live = cooc.live_mask
     src_hi = cooc.lanes["src_hi"]
@@ -200,10 +204,11 @@ def _score_and_gate(cooc: HashTable, qstore: HashTable, cfg: RankConfig,
 
     dkw = dict(decay_cfg=decay_cfg, now=now) if decay_cfg is not None else {}
     with jax.named_scope("rank.lookup_src"):
-        src_vals, src_found, src_slot = stores.lookup(qstore, src_hi,
-                                                      src_lo, **dkw)
+        src_vals, src_found, src_slot, src_stats = stores.lookup(
+            qstore, src_hi, src_lo, with_stats=True, **dkw)
     with jax.named_scope("rank.lookup_dst"):
-        dst_vals, dst_found, _ = stores.lookup(qstore, dst_hi, dst_lo, **dkw)
+        dst_vals, dst_found, _, dst_stats = stores.lookup(
+            qstore, dst_hi, dst_lo, with_stats=True, **dkw)
     with jax.named_scope("rank.score_gate"):
         if decay_cfg is not None:
             total_w = jnp.sum(lazy_decayed(decay_cfg, qstore.lanes["weight"],
@@ -241,7 +246,8 @@ def _score_and_gate(cooc: HashTable, qstore: HashTable, cfg: RankConfig,
                   & (c_ab >= cfg.min_pair_count)
                   & (src_vals["weight"] >= cfg.min_src_weight))
             score = jnp.where(ok, score, -jnp.inf)
-    return score, ok, src_slot, (src_hi, src_lo, dst_hi, dst_lo)
+    return (score, ok, src_slot, (src_hi, src_lo, dst_hi, dst_lo),
+            jnp.stack([src_stats, dst_stats]))
 
 
 def _sortable_f32(x: jax.Array) -> jax.Array:
@@ -275,8 +281,8 @@ def ranking_cycle(
     Q = qstore.capacity
     K = cfg.top_k
     L = max(cfg.bucket_rows, K)
-    score, ok, src_slot, keys = _score_and_gate(cooc, qstore, cfg,
-                                                decay_cfg, now)
+    score, ok, src_slot, keys, lookup_stats = _score_and_gate(
+        cooc, qstore, cfg, decay_cfg, now)
     src_hi, src_lo, dst_hi, dst_lo = keys
 
     # ---- sort-free stream compaction of gate-passing ROW IDS (one scatter;
@@ -353,7 +359,8 @@ def ranking_cycle(
     select_spill = jnp.sum(
         (valid_row & ((pos_in_run >= L) | (run_id >= R))).astype(jnp.int32))
     return SuggestionTable(out_src_hi, out_src_lo, out_dst_hi, out_dst_lo,
-                           out_score, n_rows, arena_spill + select_spill)
+                           out_score, n_rows, arena_spill + select_spill,
+                           lookup_stats)
 
 
 @partial(jax.jit, static_argnames=("cfg", "decay_cfg"))
@@ -370,7 +377,8 @@ def ranking_cycle_lexsort(
     mirroring the ``insert_accumulate_twopass`` pattern; not used by the
     engine."""
     C = cooc.capacity
-    score, ok, _, keys = _score_and_gate(cooc, qstore, cfg, decay_cfg, now)
+    score, ok, _, keys, lookup_stats = _score_and_gate(cooc, qstore, cfg,
+                                                       decay_cfg, now)
     src_hi, src_lo, dst_hi, dst_lo = keys
 
     # ---- compact gate-passing rows so the 3-key lexsort runs over M << C
@@ -430,7 +438,7 @@ def ranking_cycle_lexsort(
         jnp.where(keep, s_score, 0.0), mode="drop")
     n_rows = jnp.sum((is_new & s_ok).astype(jnp.int32))
     return SuggestionTable(out_src_hi, out_src_lo, out_dst_hi, out_dst_lo,
-                           out_score, n_rows, n_overflow)
+                           out_score, n_rows, n_overflow, lookup_stats)
 
 
 @partial(jax.jit, static_argnames=("cfg", "decay_cfg"))
@@ -473,8 +481,8 @@ def ranking_cycle_region(
 
     # dst marginals: the key lanes ARE the destination fingerprints.
     dkw = dict(decay_cfg=decay_cfg, now=now) if decay_cfg is not None else {}
-    dst_vals, dst_found, _ = stores.lookup(qstore, cooc.key_hi, cooc.key_lo,
-                                           **dkw)
+    dst_vals, dst_found, _, lookup_stats = stores.lookup(
+        qstore, cooc.key_hi, cooc.key_lo, with_stats=True, **dkw)
     if decay_cfg is not None:
         total_w = jnp.sum(lazy_decayed(decay_cfg, qstore.lanes["weight"],
                                        qstore.lanes["last_tick"], now))
@@ -566,7 +574,7 @@ def ranking_cycle_region(
                                   0), axis=1)
     n_overflow = jnp.sum(jnp.where(act & (posq >= S), npass_row, 0))
     return SuggestionTable(out_src_hi, out_src_lo, out_dst_hi, out_dst_lo,
-                           out_score, n_rows, n_overflow)
+                           out_score, n_rows, n_overflow, lookup_stats[None])
 
 
 def suggestions_to_host(table: SuggestionTable) -> dict:

@@ -379,7 +379,7 @@ def make_sharded_rank(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
 
     state_spec = _state_spec(cfg, axis)
     out_spec = SuggestionTable(*([P(axis)] * 5), n_rows=P(axis),
-                               n_overflow=P(axis))
+                               n_overflow=P(axis), lookup_stats=P(axis))
     fn = shard_map(body, mesh=mesh, in_specs=(state_spec,),
                    out_specs=out_spec, check_vma=False)
     return jax.jit(fn)

@@ -11,7 +11,8 @@ tables laid out as dense JAX arrays** updated by *micro-batches* of events:
   * existing keys are found with a K-round triangular probe (all rounds are
     scanned when a key may be absent, which makes lookups correct in the
     presence of pruned slots without tombstones; the sweep early-exits the
-    moment every key is resolved),
+    moment every key is resolved, and a large lookup batch runs its late
+    rounds over its unresolved rows alone, in a small tail arena),
   * finds and claims share ONE fused sweep (``_find_or_claim``): the find
     rounds also record each row's empty-slot candidates as a bitmask, then
     claim rounds resolve conflicts *batch-locally* — contenders for a slot
@@ -426,8 +427,77 @@ def _bmask(mask: jax.Array, ref: jax.Array) -> jax.Array:
     return mask.reshape(mask.shape + (1,) * (ref.ndim - 1))
 
 
+# A lookup batch of B rows hands its last unresolved rows to a tail arena of
+# B // TAIL_SHARE rows, once that many or fewer are left; batches whose
+# arena would hold fewer than TAIL_MIN_ROWS rows probe at full width only.
+TAIL_SHARE = 64
+TAIL_MIN_ROWS = 1024
+
+
+def _probe_body(table: HashTable, h0, key_hi, key_lo, live):
+    """One probe round of :func:`lookup` over the rows in ``live``."""
+    C = table.capacity
+
+    def body(st):
+        r, found = st
+        slot = _probe_slot_dyn(h0, r, C)
+        hit = live & (found < 0) \
+            & (table.key_hi[slot] == key_hi) & (table.key_lo[slot] == key_lo)
+        return r + 1, jnp.where(hit, slot.astype(jnp.int32), found)
+    return body
+
+
+def _probe_two_phase(table: HashTable, h0, key_hi, key_lo, nonzero,
+                     probe_rounds: int):
+    """The probe rounds of :func:`lookup` for a large batch.
+
+    Full-width rounds run while more than ``A = B // TAIL_SHARE`` nonzero
+    rows are unresolved. The rows still unresolved then are compacted into
+    an A-row arena (prefix sum, one scatter), which runs the remaining
+    rounds with the same round index, and its found slots are scattered
+    back. Every row sees the same probe sequence as in one full-width loop,
+    so the result is the same. Returns ``(found_slot, full_rounds,
+    tail_rows)``.
+    """
+    B = key_hi.shape[0]
+    A = B // TAIL_SHARE
+    body = _probe_body(table, h0, key_hi, key_lo, nonzero)
+
+    def cond(st):
+        r, found = st
+        return (r < probe_rounds) & (
+            jnp.sum((nonzero & (found < 0)).astype(jnp.int32)) > A)
+
+    r, found_slot = jax.lax.while_loop(
+        cond, body, (jnp.uint32(0), jnp.full((B,), -1, jnp.int32)))
+    left = nonzero & (found_slot < 0)
+    n_left = jnp.sum(left.astype(jnp.int32))
+    tail = (n_left > 0) & (r < probe_rounds)   # then n_left <= A
+
+    def run_tail(found_slot):
+        pos = jnp.cumsum(left.astype(jnp.int32)) - 1
+        idx = jnp.full((A,), B, jnp.int32).at[jnp.where(left, pos, A)].set(
+            jnp.arange(B, dtype=jnp.int32), mode="drop")
+        in_arena = idx < B
+        safe = jnp.where(in_arena, idx, 0)
+
+        def tail_cond(st):
+            r, found = st
+            return (r < probe_rounds) & jnp.any(in_arena & (found < 0))
+
+        _, found_a = jax.lax.while_loop(
+            tail_cond,
+            _probe_body(table, h0[safe], key_hi[safe], key_lo[safe],
+                        in_arena),
+            (r, jnp.full((A,), -1, jnp.int32)))
+        return found_slot.at[idx].set(found_a, mode="drop")
+
+    found_slot = jax.lax.cond(tail, run_tail, lambda f: f, found_slot)
+    return found_slot, r, jnp.where(tail, n_left, 0)
+
+
 @partial(jax.jit, static_argnames=("probe_rounds", "decay_cfg", "decay_lanes",
-                                   "tick_lane"))
+                                   "tick_lane", "with_stats"))
 def lookup(
     table: HashTable,
     key_hi: jax.Array,
@@ -438,35 +508,39 @@ def lookup(
     decay_lanes: Tuple[str, ...] = ("weight",),
     tick_lane: str = "last_tick",
     now=None,
-) -> Tuple[Dict[str, jax.Array], jax.Array, jax.Array]:
-    """Batched lookup. Returns (lanes_at_key, found_mask, slot).
+    with_stats: bool = False,
+):
+    """Batched lookup. Returns (lanes_at_key, found_mask, slot), and with
+    ``with_stats`` also ``i32[2]``: the probe rounds run over the whole
+    batch, and the rows handed to the tail arena (see
+    :func:`_probe_two_phase`; batches of fewer than
+    ``TAIL_SHARE * TAIL_MIN_ROWS`` rows never hand any).
 
     Lazy decay policy (``decay_cfg`` + ``now``): the returned ``decay_lanes``
     are the *read-time decayed view* ``w * factor(now - last_tick)`` — the
     store itself is untouched; maintenance is amortized into reads.
     """
-    C = table.capacity
     key_hi = jnp.asarray(key_hi, jnp.uint32)
     key_lo = jnp.asarray(key_lo, jnp.uint32)
     h0 = probe_hash(key_hi, key_lo)
     B = key_hi.shape[0]
     nonzero = (key_hi != 0) | (key_lo != 0)
 
-    # while_loop with early exit: most batches resolve in 1-2 rounds (only
-    # genuinely-absent nonzero keys force the full prune-safe scan).
-    def cond(st):
-        r, found = st
-        return (r < probe_rounds) & jnp.any(nonzero & (found < 0))
+    if B // TAIL_SHARE >= TAIL_MIN_ROWS:
+        found_slot, r, tail_rows = _probe_two_phase(
+            table, h0, key_hi, key_lo, nonzero, probe_rounds)
+    else:
+        # while_loop with early exit: most batches resolve in 1-2 rounds
+        # (only genuinely-absent nonzero keys force the full prune-safe
+        # scan).
+        def cond(st):
+            r, found = st
+            return (r < probe_rounds) & jnp.any(nonzero & (found < 0))
 
-    def body(st):
-        r, found = st
-        slot = _probe_slot_dyn(h0, r, C)
-        hit = nonzero & (found < 0) \
-            & (table.key_hi[slot] == key_hi) & (table.key_lo[slot] == key_lo)
-        return r + 1, jnp.where(hit, slot.astype(jnp.int32), found)
-
-    _, found_slot = jax.lax.while_loop(
-        cond, body, (jnp.uint32(0), jnp.full((B,), -1, jnp.int32)))
+        r, found_slot = jax.lax.while_loop(
+            cond, _probe_body(table, h0, key_hi, key_lo, nonzero),
+            (jnp.uint32(0), jnp.full((B,), -1, jnp.int32)))
+        tail_rows = 0
     found = found_slot >= 0
     safe = jnp.where(found, found_slot, 0)
     f = None
@@ -478,6 +552,10 @@ def lookup(
         if f is not None and name in decay_lanes:
             v = v * f
         out[name] = jnp.where(_bmask(found, v), v, jnp.zeros_like(v))
+    if with_stats:
+        stats = jnp.stack([r.astype(jnp.int32),
+                           jnp.asarray(tail_rows, jnp.int32)])
+        return out, found, found_slot, stats
     return out, found, found_slot
 
 

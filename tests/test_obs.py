@@ -2,6 +2,7 @@
 the bounded buffer, the compile listener, the profiler's host plane, the
 named scopes of the rank and ingest programs, and the host syncs of the
 engine's host loop."""
+import dataclasses
 import glob
 import os
 import re
@@ -167,9 +168,10 @@ def engine():
 
 
 # one per blocking read in the call's code: int(tick) before and after
-# ingest_many; int(tick), int(n_rows), int(n_overflow) after the rank program
+# ingest_many; int(tick), then the table's n_rows, n_overflow and lookup
+# stats in one read, after the rank program
 @pytest.mark.parametrize("call,syncs", [("step_many", 2),
-                                        ("run_rank_cycle", 3)])
+                                        ("run_rank_cycle", 2)])
 def test_engine_syncs_per_call(engine, call, syncs):
     stack = _stack(4, t0=int(engine.state.tick))
     w = _during(lambda: engine.step_many(stack) if call == "step_many"
@@ -183,3 +185,28 @@ def test_rank_cycle_spans_and_rows_exported(engine):
     assert engine.suggestions
     assert w["rank.wait"][0] == w["rank.to_host"][0] == 1
     assert w["rank.rows_exported"][0] == len(engine.suggestions)
+
+
+def test_rank_cycle_counts_lookup_rounds_and_tail_rows():
+    """At a cooc capacity of 2^16 the rank cycle's two marginal lookups
+    (2^16 rows each, ~1,500 live) run one full-width round and finish in
+    the tail arena; the cycle counts each lookup's full-width rounds and
+    tail rows once, as the rank program returns them, within the cycle's
+    two host syncs."""
+    cfg = dataclasses.replace(_cfg(), cooc_capacity=1 << 16)
+    eng = SearchAssistanceEngine(cfg)
+    eng.step_many(_stack(16))
+    want = np.asarray(ranking.ranking_cycle(
+        eng.state.cooc, eng.state.qstore, cfg.rank, decay_cfg=cfg.decay,
+        now=eng.state.tick).lookup_stats)
+    t0 = time.perf_counter()
+    eng.run_rank_cycle()
+    t1 = time.perf_counter()
+    counts = [(name, n) for name, t, n in obs.RECORDER.counts
+              if t0 <= t <= t1]
+    got = [[n for name, n in counts if name == f"rank.lookup_{k}"]
+           for k in ("full_rounds", "tail_rows")]
+    assert np.array_equal(np.asarray(got).T, want)
+    assert want.shape == (2, 2)
+    assert (want[:, 0] == 1).all() and (want[:, 1] > 0).all()
+    assert sum(name == "engine.syncs" for name, _ in counts) == 2

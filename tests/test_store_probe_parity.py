@@ -381,3 +381,77 @@ def test_claim_winners_lexsort_fallback_matches_packed():
         contenders = np.nonzero(cn & (sl == s))[0]
         winners = np.nonzero(wn & (sl == s))[0]
         assert len(winners) == 1 and winners[0] == contenders.min()
+
+
+# ---------------------------------------------------------------------------
+# Two-phase lookup: full-width rounds, then a tail arena
+# ---------------------------------------------------------------------------
+
+def _probe_depth(t, hi, lo, slot):
+    """The probe round at which each found row's key sat."""
+    h0 = np.asarray(stores.probe_hash(jnp.asarray(hi), jnp.asarray(lo)),
+                    np.int64)
+    r = np.arange(16)
+    cand = (h0[:, None] + r * (r + 1) // 2) & (t.capacity - 1)
+    return np.argmax(cand == np.asarray(slot)[:, None], axis=1)
+
+
+def _lookup_case(case, rng):
+    """(table, key fps, lookup kwargs, (full rounds, tail engaged))."""
+    B = 1 << 17
+    near_full = case == "near_full"
+    cap, n = (1 << 11, 1950) if near_full else (1 << 17, 3000)
+    fps = (rng.choice(1 << 62, size=n, replace=False) + 1).astype(np.uint64)
+    absent = (rng.choice(1 << 62, size=4096 if case == "many_absent" else 64,
+                         replace=False) + (1 << 62)).astype(np.uint64)
+    t = _ins(stores.insert_accumulate, _mk(cap), fps, rng.random(n))
+    t = t._replace(lanes=dict(t.lanes, last_tick=jnp.asarray(
+        rng.integers(0, 50, cap), jnp.int32)))
+    if near_full:
+        # every key once, then rows found in round 0 up to a full phase 1
+        hi, lo = split_fp(fps)
+        _, found, slot = stores.lookup(t, jnp.asarray(hi), jnp.asarray(lo))
+        depth = _probe_depth(t, hi, lo, slot)
+        shallow = fps[np.asarray(found) & (depth == 0)]
+        present = np.concatenate([fps, rng.choice(shallow, size=8192)])
+    else:
+        present = rng.choice(fps, size=30000)
+    keys = np.zeros(B, np.uint64)
+    live = np.concatenate([present, absent])
+    keys[rng.choice(B, size=len(live), replace=False)] = live
+    kw = (dict(decay_cfg=DecayConfig(policy="lazy"), now=jnp.int32(60))
+          if case == "low_load_decayed" else {})
+    return t, keys, kw, ((16, False) if case == "many_absent" else (1, True))
+
+
+@pytest.mark.parametrize("case", ["low_load", "many_absent", "near_full",
+                                  "low_load_decayed"])
+def test_two_phase_lookup_matches_full_width_loop(case):
+    """A batch of 2^17 rows (tail arena 2^11) returns what the same keys
+    return in chunks of 8,192 rows, which run the full-width loop alone:
+    bit-identical lanes, found mask and slot. Low load hands the rest to
+    the tail after one round; more absent keys than the arena holds keep
+    all 16 rounds at full width; a near-full table leaves keys 10 and more
+    rounds deep for the tail."""
+    t, keys, kw, (want_rounds, want_tail) = _lookup_case(
+        case, np.random.default_rng(14))
+    hi, lo = split_fp(keys)
+    vals, found, slot, stats = stores.lookup(
+        t, jnp.asarray(hi), jnp.asarray(lo), with_stats=True, **kw)
+    parts = [stores.lookup(t, jnp.asarray(hi[i:i + 8192]),
+                           jnp.asarray(lo[i:i + 8192]), **kw)
+             for i in range(0, len(keys), 8192)]
+    full_rounds, tail_rows = np.asarray(stats)
+    assert full_rounds == want_rounds and (tail_rows > 0) == want_tail
+    np.testing.assert_array_equal(
+        np.asarray(slot), np.concatenate([np.asarray(p[2]) for p in parts]))
+    np.testing.assert_array_equal(
+        np.asarray(found), np.concatenate([np.asarray(p[1]) for p in parts]))
+    for name, v in vals.items():
+        np.testing.assert_array_equal(
+            np.asarray(v), np.concatenate([np.asarray(p[0][name])
+                                           for p in parts]))
+    f = np.asarray(found)
+    assert 0 < f.sum() < np.count_nonzero(keys)
+    if case == "near_full":
+        assert _probe_depth(t, hi[f], lo[f], np.asarray(slot)[f]).max() >= 10
