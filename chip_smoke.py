@@ -26,9 +26,9 @@ Phases, one result line each:
     [serve]   peak device bytes
 
 ``--chips 4`` runs only the sharded engine over a 4-chip mesh (cooc 2^25
-per shard) against ReferenceEngine. The last stdout line is the JSON
-result; any failed phase exits non-zero. Everything runs in this one
-process: a chip belongs to one process at a time.
+per shard), queries and tweets, against ReferenceEngine. The last stdout
+line is the JSON result; any failed phase exits non-zero. Everything runs
+in this one process: a chip belongs to one process at a time.
 """
 from __future__ import annotations
 
@@ -102,15 +102,8 @@ def hose(sz: Sizes, seed: int):
 def backlog(stream, t0: int, n_ticks: int):
     """Ticks ``[t0, t0 + n_ticks)`` of ``stream`` as one device TickStack,
     stacked as a replayed log chunk."""
-    import numpy as np
-    from repro.streaming import LogChunk, chunk_to_stack
-    ticks = [stream.gen_tick(t) for t in range(t0, t0 + n_ticks)]
-    col = lambda f: np.stack([f(ev, tw) for ev, tw in ticks])
-    return chunk_to_stack(LogChunk(
-        ticks=np.arange(t0, t0 + n_ticks), sess_fp=col(lambda e, w: e.sess_fp),
-        q_fp=col(lambda e, w: e.q_fp), src=col(lambda e, w: e.src),
-        q_valid=col(lambda e, w: e.valid), grams=col(lambda e, w: w.grams),
-        t_valid=col(lambda e, w: w.valid)))
+    from repro.streaming import ticks_to_stack
+    return ticks_to_stack([stream.gen_tick(t) for t in range(t0, t0 + n_ticks)])
 
 
 # ---------------------------------------------------------------------------
@@ -411,54 +404,47 @@ def phase_parity(cfg, plan, st, table):
 
 def phase_sharded(sz: Sizes, n_chips: int, seed: int):
     """The sharded engine over a ``n_chips`` mesh, total cooc ``n_chips *
-    sz.cooc_capacity`` (one engine's store per shard), vs ReferenceEngine."""
+    sz.cooc_capacity`` (one engine's store per shard): queries in two
+    ingest quanta a tick and tweets, replayed through
+    ``ShardedEngine.step_many``, vs ReferenceEngine."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh
     from repro.core import sharded_engine as se
-    from repro.core.hashing import split_fp
     from repro.core.reference import ReferenceEngine
     from repro.data.stream import StreamConfig, SyntheticStream
+    from repro.streaming import ticks_to_stack
 
     mesh = Mesh(np.array(jax.devices()[:n_chips]), ("shard",))
     ecfg = dataclasses.replace(engine_config(sz),
                                cooc_capacity=n_chips * sz.cooc_capacity,
                                session_capacity=n_chips * sz.session_capacity)
     scfg = se.ShardedConfig(base=ecfg)
-    step = se.make_sharded_step(scfg, mesh)
-    decay = se.make_sharded_decay(scfg, mesh)
+    eng = se.ShardedEngine(scfg, mesh)
     rank = se.make_sharded_rank(scfg, mesh)
-    state = se.init_sharded_state(scfg, mesh)
-    per_dev = state_bytes(state) // n_chips
-    stream = SyntheticStream(StreamConfig(queries_per_tick=sz.correct_batch,
-                                          tweets_per_tick=0), seed=seed)
+    per_dev = state_bytes(eng.state) // n_chips
+    stream = SyntheticStream(StreamConfig(
+        queries_per_tick=2 * sz.correct_batch, tweets_per_tick=sz.tweets,
+        tweet_grams=sz.grams), seed=seed)
+    ticks = [stream.gen_tick(t) for t in range(sz.correct_ticks)]
     ref = ReferenceEngine(ecfg)
     t0 = time.perf_counter()
-    for t in range(sz.correct_ticks):
-        ev, _ = stream.gen_tick(t)
-        s_hi, s_lo = split_fp(ev.sess_fp)
-        q_hi, q_lo = split_fp(ev.q_fp)
-        state = step(state, jnp.asarray(s_hi), jnp.asarray(s_lo),
-                     jnp.asarray(q_hi), jnp.asarray(q_lo),
-                     jnp.asarray(ev.src, jnp.int32), jnp.asarray(ev.valid))
-        if t > 0 and t % ecfg.decay_every == 0:
-            state = decay(state, jnp.int32(ecfg.decay_every))
-        state = state._replace(tick=state.tick + 1)
-        ref.step(ev, None)
-    merged = se.merge_sharded_suggestions(rank(state), ecfg.rank.top_k)
+    eng.step_many(ticks_to_stack(ticks))
+    for ev, tw in ticks:
+        ref.step(ev, tw)
+    merged = se.merge_sharded_suggestions(rank(eng.state), ecfg.rank.top_k)
     ref.rank_cycle()
-    drops = int(np.asarray(state.n_route_drop).sum())
+    drops = int(np.asarray(eng.state.n_route_drop).sum())
     want = ref.suggestions
     check(set(merged) == set(want),
           f"source sets differ: sharded {len(merged)} vs ref {len(want)}")
     n, bad, err = compare_tables(merged, want)
-    per_shard = np.asarray(state.cooc.live_mask).reshape(n_chips, -1).sum(1)
+    per_shard = np.asarray(eng.state.cooc.live_mask).reshape(n_chips, -1).sum(1)
     print(f"[sharded] {n_chips} shards x cooc {sz.cooc_capacity} "
           f"({per_dev} B state per device): {sz.correct_ticks} ticks x "
-          f"{sz.correct_batch} events, {n} sources: {bad} top-k "
-          f"disagreements, max score error {err:.2e}; route drops={drops}, "
-          f"per-shard pairs={per_shard.tolist()} "
+          f"{2 * sz.correct_batch} events + {sz.tweets} tweets, {n} sources: "
+          f"{bad} top-k disagreements, max score error {err:.2e}; route "
+          f"drops={drops}, per-shard pairs={per_shard.tolist()} "
           f"({time.perf_counter() - t0:.1f}s)", flush=True)
     check(n > 0 and bad == 0, f"{bad} of {n} top-k lists disagree")
     check(drops == 0, f"{drops} routed pairs dropped")

@@ -289,6 +289,58 @@ def ingest_queries_stack(state: EngineState, sess_hi, sess_lo, q_hi, q_lo,
     return state
 
 
+class GramPairs(NamedTuple):
+    """Ordered (src -> dst) pairs among the query-like grams of tweets."""
+    src_hi: jax.Array
+    src_lo: jax.Array
+    dst_hi: jax.Array
+    dst_lo: jax.Array
+    valid: jax.Array
+
+
+def tweet_grams(qstore: HashTable, g_hi: jax.Array, g_lo: jax.Array,
+                valid: jax.Array, tick: jax.Array, cfg: EngineConfig,
+                part: Optional[Tuple[jax.Array, int]] = None
+                ) -> Tuple[HashTable, GramPairs]:
+    """The tweet path of §4.3 up to its cooccurrence insert, shared by
+    :func:`ingest_tweets` and the sharded engine: a gram is query-like
+    when the query store holds it with ``min_querylike_count``; the
+    query-like grams add ``tweet_weight`` to the query store, and every
+    ordered pair of distinct query-like grams of one tweet is returned.
+    ``part = (start, size)`` returns the pairs of tweets ``[start, start +
+    size)`` only (``start`` may be traced); the query store takes every
+    tweet either way."""
+    T, G = g_hi.shape
+    flat_hi, flat_lo = g_hi.reshape(-1), g_lo.reshape(-1)
+    vals, found, _ = stores.lookup(qstore, flat_hi, flat_lo,
+                                   probe_rounds=cfg.probe_rounds)
+    querylike = (found & (vals["count"] >= cfg.min_querylike_count)
+                 & valid[:, None].repeat(G, 1).reshape(-1))
+    B = T * G
+    tick_vec = jnp.full((B,), tick, jnp.int32)
+    w = jnp.full((B,), cfg.tweet_weight, jnp.float32)
+    dkw = dict(decay_cfg=cfg.decay, now=tick) if cfg.lazy_decay else {}
+    qstore = stores.insert_accumulate(
+        qstore, flat_hi, flat_lo,
+        {"weight": w, "count": jnp.ones((B,), jnp.float32), "last_tick": tick_vec},
+        querylike, modes=_Q_MODES, probe_rounds=cfg.probe_rounds, **dkw)
+
+    # all ordered pairs among query-like grams of the same tweet
+    ql = querylike.reshape(T, G)
+    if part is not None:
+        start, T = part
+        g_hi, g_lo, ql = (jax.lax.dynamic_slice_in_dim(x, start, T)
+                          for x in (g_hi, g_lo, ql))
+    src_hi = jnp.broadcast_to(g_hi[:, :, None], (T, G, G)).reshape(-1)
+    src_lo = jnp.broadcast_to(g_lo[:, :, None], (T, G, G)).reshape(-1)
+    dst_hi = jnp.broadcast_to(g_hi[:, None, :], (T, G, G)).reshape(-1)
+    dst_lo = jnp.broadcast_to(g_lo[:, None, :], (T, G, G)).reshape(-1)
+    ok = (ql[:, :, None] & ql[:, None, :]).reshape(-1)
+    same = (src_hi == dst_hi) & (src_lo == dst_lo)
+    ok = ok & ~same
+    return qstore, GramPairs(src_hi, src_lo, dst_hi, dst_lo, ok)
+
+
 @partial(jax.jit, static_argnames=("cfg",))
 def ingest_tweets(
     state: EngineState,
@@ -297,35 +349,14 @@ def ingest_tweets(
     *, cfg: EngineConfig,
 ) -> EngineState:
     """The tweet path of §4.3 for one micro-batch of tweets."""
-    T, G = g_hi.shape
-    flat_hi, flat_lo = g_hi.reshape(-1), g_lo.reshape(-1)
-    vals, found, _ = stores.lookup(state.qstore, flat_hi, flat_lo,
-                                   probe_rounds=cfg.probe_rounds)
-    querylike = (found & (vals["count"] >= cfg.min_querylike_count)
-                 & valid[:, None].repeat(G, 1).reshape(-1))
-    B = T * G
-    tick_vec = jnp.full((B,), state.tick, jnp.int32)
-    w = jnp.full((B,), cfg.tweet_weight, jnp.float32)
     dkw = dict(decay_cfg=cfg.decay, now=state.tick) if cfg.lazy_decay else {}
-    qstore = stores.insert_accumulate(
-        state.qstore, flat_hi, flat_lo,
-        {"weight": w, "count": jnp.ones((B,), jnp.float32), "last_tick": tick_vec},
-        querylike, modes=_Q_MODES, probe_rounds=cfg.probe_rounds, **dkw)
-
-    # all ordered pairs among query-like grams of the same tweet
-    ql = querylike.reshape(T, G)
-    src_hi = jnp.broadcast_to(g_hi[:, :, None], (T, G, G)).reshape(-1)
-    src_lo = jnp.broadcast_to(g_lo[:, :, None], (T, G, G)).reshape(-1)
-    dst_hi = jnp.broadcast_to(g_hi[:, None, :], (T, G, G)).reshape(-1)
-    dst_lo = jnp.broadcast_to(g_lo[:, None, :], (T, G, G)).reshape(-1)
-    ok = (ql[:, :, None] & ql[:, None, :]).reshape(-1)
-    same = (src_hi == dst_hi) & (src_lo == dst_lo)
-    ok = ok & ~same
-    P = src_hi.shape[0]
+    qstore, pairs = tweet_grams(state.qstore, g_hi, g_lo, valid, state.tick,
+                                cfg)
+    P = pairs.src_hi.shape[0]
     cooc = cooc_insert_pairs(
-        state.cooc, qstore, src_hi, src_lo, dst_hi, dst_lo,
-        jnp.full((P,), cfg.tweet_weight, jnp.float32), ok, state.tick,
-        cfg, dkw)
+        state.cooc, qstore, pairs.src_hi, pairs.src_lo, pairs.dst_hi,
+        pairs.dst_lo, jnp.full((P,), cfg.tweet_weight, jnp.float32),
+        pairs.valid, state.tick, cfg, dkw)
     return EngineState(qstore, cooc, state.sessions, state.tick)
 
 

@@ -21,11 +21,17 @@ axis and removes the memory wall:
     merge of the per-shard lists is the exact global top-k (scores read
     only the pair and the replicated query-store marginals);
   * pair routing: fixed-capacity bucketization + ``all_to_all`` along the
-    shard axis (overflow is dropped *and counted*, mirroring the paper's
-    rate-limiting stance).
+    shard axis, one route per ingest quantum slice of the query events and
+    one per tick for the tweets (overflow is dropped *and counted*; a
+    deployment sizes the capacities so that none is);
+  * tweets: every shard applies the whole tick's query-like grams to its
+    query-store replica; shard i expands the gram pairs of its 1/n of the
+    tweets and routes them like session pairs.
 
 State lives as arrays with a leading shard axis, sharded with shard_map;
-the same single-device store/ranking code runs per shard.
+the same single-device store/ranking code runs per shard. ``ShardedEngine``
+drives it from the host and replays ``TickStack``s from the shared durable
+log, as ``SearchAssistanceEngine`` does on one chip.
 """
 from __future__ import annotations
 
@@ -38,11 +44,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
+from .. import obs
 from . import ranking, stores
 from .decay import (prune_sweep, region_decay_sweep, region_prune_sweep,
                     sweep_decay_prune)
-from .engine import (EngineConfig, cooc_insert_pairs, maintenance_cadence,
-                     make_cooc_store, _Q_MODES)
+from .engine import (EngineConfig, TickStack, _sync, cooc_insert_pairs,
+                     maintenance_cadence, make_cooc_store, quantum_slices,
+                     tweet_grams, _Q_MODES)
 from .hashing import combine_fp_device, probe_hash
 from .ranking import SuggestionTable
 from .stores import HashTable, SessionTable
@@ -52,7 +60,11 @@ from .stores import HashTable, SessionTable
 class ShardedConfig:
     base: EngineConfig
     n_salts: int = 4                # shards one source's pairs spread over
-    route_capacity: int = 4096      # per-destination bucket capacity
+    # per-destination bucket capacities: the session pairs of one quantum
+    # slice (``base.ingest_quantum`` query events), the tweet pairs of one
+    # tick. A pair beyond them is dropped and counted (``n_route_drop``).
+    route_capacity: int = 4096
+    tweet_route_capacity: int = 4096
 
 
 class ShardedState(NamedTuple):
@@ -60,7 +72,8 @@ class ShardedState(NamedTuple):
     cooc: HashTable          # leading dim = shard
     sessions: SessionTable   # leading dim = shard
     tick: jax.Array
-    n_route_drop: jax.Array  # routed pairs dropped on bucket overflow
+    n_route_drop: jax.Array  # per shard: routed pairs dropped on overflow
+    n_routed: jax.Array      # per shard: pairs received (u32, wraps)
 
 
 def _stack_shards(tree, n):
@@ -103,6 +116,7 @@ def init_sharded_state(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"
             sessions=_stack_shards(sessions, n),
             tick=jnp.zeros((), jnp.int32),
             n_route_drop=jnp.zeros((n,), jnp.int32),
+            n_routed=jnp.zeros((n,), jnp.uint32),
         )
 
     shardings = jax.tree.map(lambda p: NamedSharding(mesh, p),
@@ -120,113 +134,162 @@ def pair_owner(src_hi, src_lo, dst_hi, dst_lo, n_salts: int, n_shards: int):
             % jnp.uint32(n_shards)).astype(jnp.int32)
 
 
-def _route(pairs_key_hi, pairs_key_lo, owner, payload: Dict[str, jax.Array],
-           valid, n_shards: int, cap: int, axis: str):
-    """Bucketize by owner shard and all_to_all. Returns routed flat arrays.
-
-    All arrays are per-device (inside shard_map). Overflow beyond ``cap``
-    per destination bucket is dropped and counted.
-    """
-    Bp = pairs_key_hi.shape[0]
-    owner = jnp.where(valid, owner, n_shards)  # invalid -> sentinel bucket
-    order = jnp.argsort(owner)                  # stable
-    o_sorted = owner[order]
-    # position within each owner run
-    idx = jnp.arange(Bp, dtype=jnp.int32)
-    seg_start = jax.ops.segment_min(
-        idx, jnp.clip(o_sorted, 0, n_shards).astype(jnp.int32),
-        num_segments=n_shards + 1)
-    pos = idx - seg_start[jnp.clip(o_sorted, 0, n_shards)]
-    ok = (o_sorted < n_shards) & (pos < cap)
-    dropped = jnp.sum(((o_sorted < n_shards) & (pos >= cap)).astype(jnp.int32))
-
-    dest_row = jnp.where(ok, o_sorted.astype(jnp.int32), n_shards)
-    dest_pos = jnp.where(ok, pos, 0)
-
-    def bucketize(x, fill=0):
-        buf = jnp.full((n_shards, cap) + x.shape[1:], fill, x.dtype)
-        return buf.at[dest_row, dest_pos].set(x[order], mode="drop")
-
-    b_hi = bucketize(pairs_key_hi)
-    b_lo = bucketize(pairs_key_lo)
-    b_payload = {k: bucketize(v) for k, v in payload.items()}
-    b_valid = jnp.zeros((n_shards, cap), bool).at[dest_row, dest_pos].set(
-        ok, mode="drop")
-
-    # exchange: axis 0 is the destination shard
-    t_hi = jax.lax.all_to_all(b_hi, axis, 0, 0, tiled=False)
-    t_lo = jax.lax.all_to_all(b_lo, axis, 0, 0, tiled=False)
-    t_val = jax.lax.all_to_all(b_valid, axis, 0, 0, tiled=False)
-    t_payload = {k: jax.lax.all_to_all(v, axis, 0, 0, tiled=False)
-                 for k, v in b_payload.items()}
-    flat = lambda x: x.reshape((n_shards * cap,) + x.shape[2:])
-    return (flat(t_hi), flat(t_lo), {k: flat(v) for k, v in t_payload.items()},
-            flat(t_val), dropped)
+# bytes of one routed pair: four u32 key halves and the valid flag; the
+# session pairs carry their f32 weight besides
+_PAIR_BYTES = 4 * 4 + 1
+_WEIGHT_BYTES = 4
 
 
-def _ingest_body(cfg: ShardedConfig, n: int, axis: str):
-    """The per-device query-path ingest body (shared by the one-tick step
-    and the fused multi-tick replay scan)."""
+def route_bytes_per_tick(cfg: ShardedConfig, n_shards: int, n_events: int,
+                         n_tweets: int, n_grams: int) -> int:
+    """Bytes one tick's ``all_to_all``s send off each chip: every route
+    sends its ``n_shards - 1`` foreign buckets whole, whatever they hold —
+    one route per quantum slice of the query events, one for the tweets."""
+    q = (len(quantum_slices(n_events, cfg.base.ingest_quantum))
+         * cfg.route_capacity * (_PAIR_BYTES + _WEIGHT_BYTES)
+         if n_events else 0)
+    t = cfg.tweet_route_capacity * _PAIR_BYTES if n_tweets and n_grams else 0
+    return (n_shards - 1) * (q + t)
+
+
+def _route(owner, lanes: Dict[str, jax.Array], valid, n_shards: int,
+           cap: int, axis: str):
+    """Bucketize pairs by owner shard and exchange the buckets with
+    ``all_to_all`` (inside shard_map). A bucket holds ``cap`` pairs in
+    arrival order; a pair beyond it is dropped and counted. Returns the
+    received lanes and valid flags, flat ``[n_shards * cap]``, the pairs
+    dropped here and the pairs received here."""
+    to = valid[:, None] & (owner[:, None] == jnp.arange(n_shards)[None, :])
+    pos = jnp.cumsum(to, axis=0, dtype=jnp.int32) - 1      # [P, n_shards]
+    dropped = jnp.sum(jnp.maximum(pos[-1] + 1 - cap, 0))
+    pos = jnp.sum(jnp.where(to, pos, 0), axis=1)
+    ok = valid & (pos < cap)
+    row = jnp.where(ok, owner, n_shards)     # out of range: not written
+    col = jnp.where(ok, pos, 0)
+
+    def exchange(x):
+        buf = jnp.zeros((n_shards, cap), x.dtype).at[row, col].set(
+            x, mode="drop")
+        # axis 0 is the destination shard
+        got = jax.lax.all_to_all(buf, axis, 0, 0, tiled=False)
+        return got.reshape(n_shards * cap)
+
+    got_valid = exchange(ok)
+    return ({k: exchange(v) for k, v in lanes.items()}, got_valid, dropped,
+            jnp.sum(got_valid, dtype=jnp.uint32))
+
+
+def _each_quantum(body, st, lanes, quantum: int):
+    """``body(st, *slice)`` over one tick's query lanes cut at
+    ``quantum_slices``, in order: the whole slices in one ``lax.scan``,
+    a shorter last slice after it."""
+    B = lanes[0].shape[0]
+    size = quantum_slices(B, quantum)[0][1]
+    k = B // size
+    if k > 1:
+        st, _ = jax.lax.scan(
+            lambda s, xs: (body(s, *xs), None), st,
+            tuple(x[:k * size].reshape((k, size) + x.shape[1:])
+                  for x in lanes))
+    else:
+        st = body(st, *(x[:size] for x in lanes))
+    if k * size < B:
+        st = body(st, *(x[k * size:] for x in lanes))
+    return st
+
+
+def _tick_body(cfg: ShardedConfig, n: int, axis: str):
+    """One tick on one device (inside shard_map), what
+    ``SearchAssistanceEngine.step`` does to its state: the query path in
+    the quantum slices of ``engine.ingest_many``, the tweet path, the
+    cadence maintenance, the tick advance. Shared by the live tick step
+    and the replay scan, so the two mutate state alike."""
     base = cfg.base
+    n_sw = len(base.source_weights)
 
-    def body(state: ShardedState, s_hi, s_lo, q_hi, q_lo, src, valid):
-        me = jax.lax.axis_index(axis)
-        B = q_hi.shape[0]
-        tick_vec = jnp.full((B,), state.tick, jnp.int32)
-        sw = jnp.asarray(base.source_weights, jnp.float32)
-        w = sw[jnp.clip(src, 0, len(base.source_weights) - 1)]
+    def dkw(st):
         # lazy decay policy: same rebase-on-write as the unsharded engine
-        dkw = (dict(decay_cfg=base.decay, now=state.tick)
-               if base.lazy_decay else {})
+        return (dict(decay_cfg=base.decay, now=st.tick)
+                if base.lazy_decay else {})
 
-        # --- replicated query store: every shard applies the full batch ---
-        qstore = stores.insert_accumulate(
-            state.qstore, q_hi, q_lo,
-            {"weight": w, "count": jnp.ones((B,), jnp.float32),
-             "last_tick": tick_vec},
-            valid, modes=_Q_MODES, probe_rounds=base.probe_rounds, **dkw)
+    def route_insert(st, qstore, src_hi, src_lo, dst_hi, dst_lo, w, valid,
+                     cap):
+        """Send each pair to the shard that owns it; insert what this
+        shard receives. ``w`` None: every pair weighs ``tweet_weight``."""
+        with jax.named_scope("ingest.route"):
+            owner = pair_owner(src_hi, src_lo, dst_hi, dst_lo, cfg.n_salts,
+                               n)
+            lanes = {"src_hi": src_hi, "src_lo": src_lo, "dst_hi": dst_hi,
+                     "dst_lo": dst_lo}
+            if w is not None:
+                lanes["w"] = w
+            got, ok, dropped, received = _route(owner, lanes, valid, n, cap,
+                                                axis)
+        with jax.named_scope("ingest.cooc_insert"):
+            w = got["w"] if w is not None else jnp.full(
+                ok.shape, base.tweet_weight, jnp.float32)
+            cooc = cooc_insert_pairs(
+                st.cooc, qstore, got["src_hi"], got["src_lo"], got["dst_hi"],
+                got["dst_lo"], w, ok, st.tick, base, dkw(st))
+        return st._replace(qstore=qstore, cooc=cooc,
+                           n_route_drop=st.n_route_drop + dropped[None],
+                           n_routed=st.n_routed + received[None])
 
-        # --- sessions: filter to my shard (owner = hash(sess) % n) ---
-        sess_owner = (probe_hash(s_hi, s_lo) % jnp.uint32(n)).astype(jnp.int32)
-        mine = valid & (sess_owner == me)
-        sessions, pairs = stores.update_sessions(
-            state.sessions, s_hi, s_lo, q_hi, q_lo, src, state.tick, mine,
-            probe_rounds=base.probe_rounds)
+    def query_slice(st, s_hi, s_lo, q_hi, q_lo, src, valid):
+        B = q_hi.shape[0]
+        sw = jnp.asarray(base.source_weights, jnp.float32)
+        # replicated query store: every shard applies the whole slice
+        with jax.named_scope("ingest.qstore"):
+            qstore = stores.insert_accumulate(
+                st.qstore, q_hi, q_lo,
+                {"weight": sw[jnp.clip(src, 0, n_sw - 1)],
+                 "count": jnp.ones((B,), jnp.float32),
+                 "last_tick": jnp.full((B,), st.tick, jnp.int32)},
+                valid, modes=_Q_MODES, probe_rounds=base.probe_rounds,
+                **dkw(st))
+        # sessions: each shard keeps those it owns (hash(sess) % n)
+        with jax.named_scope("ingest.sessions"):
+            owner = (probe_hash(s_hi, s_lo) % jnp.uint32(n)).astype(jnp.int32)
+            sessions, pairs = stores.update_sessions(
+                st.sessions, s_hi, s_lo, q_hi, q_lo, src, st.tick,
+                valid & (owner == jax.lax.axis_index(axis)),
+                probe_rounds=base.probe_rounds)
+        # pair weight: geometric mean of the two interaction-source weights
+        w_pair = jnp.sqrt(sw[jnp.clip(pairs.src_code, 0, n_sw - 1)]
+                          * sw[jnp.clip(pairs.dst_code, 0, n_sw - 1)])
+        return route_insert(st._replace(sessions=sessions), qstore,
+                            pairs.src_hi, pairs.src_lo, pairs.dst_hi,
+                            pairs.dst_lo, w_pair, pairs.valid,
+                            cfg.route_capacity)
 
-        # --- route pairs to their cooccurrence owner ---
-        owner = pair_owner(pairs.src_hi, pairs.src_lo, pairs.dst_hi,
-                           pairs.dst_lo, cfg.n_salts, n)
-        w_src = sw[jnp.clip(pairs.src_code, 0, len(base.source_weights) - 1)]
-        w_dst = sw[jnp.clip(pairs.dst_code, 0, len(base.source_weights) - 1)]
-        w_pair = jnp.sqrt(w_src * w_dst)
-        payload = {"src_hi": pairs.src_hi, "src_lo": pairs.src_lo,
-                   "dst_hi": pairs.dst_hi, "dst_lo": pairs.dst_lo,
-                   "w": w_pair}
-        r_hi, r_lo, r_pl, r_valid, drop = _route(
-            pairs.src_hi, pairs.src_lo, owner, payload, pairs.valid,
-            n, cfg.route_capacity, axis)
-        cooc = cooc_insert_pairs(
-            state.cooc, qstore, r_pl["src_hi"], r_pl["src_lo"],
-            r_pl["dst_hi"], r_pl["dst_lo"], r_pl["w"], r_valid, state.tick,
-            base, dkw)
+    def tweets(st, g_hi, g_lo, t_valid):
+        # every shard looks up and adds every tweet's grams (the query store
+        # is replicated); shard i expands the pairs of its own 1/n of them
+        pad = -g_hi.shape[0] % n
+        if pad:
+            g_hi, g_lo = (jnp.pad(x, ((0, pad), (0, 0))) for x in (g_hi, g_lo))
+            t_valid = jnp.pad(t_valid, (0, pad))
+        part = g_hi.shape[0] // n
+        qstore, pairs = tweet_grams(
+            st.qstore, g_hi, g_lo, t_valid, st.tick, base,
+            part=(jax.lax.axis_index(axis) * part, part))
+        return route_insert(st, qstore, pairs.src_hi, pairs.src_lo,
+                            pairs.dst_hi, pairs.dst_lo, None, pairs.valid,
+                            cfg.tweet_route_capacity)
 
-        return ShardedState(qstore, cooc, sessions, state.tick,
-                            state.n_route_drop + drop[None])
+    def tick(st: ShardedState, xs: TickStack) -> ShardedState:
+        if xs.q_hi.shape[0] > 0:
+            st = _each_quantum(query_slice, st,
+                               (xs.sess_hi, xs.sess_lo, xs.q_hi, xs.q_lo,
+                                xs.src, xs.q_valid), base.ingest_quantum)
+        if xs.g_hi.shape[0] > 0 and xs.g_hi.shape[1] > 0:
+            with jax.named_scope("ingest.tweets"):
+                st = tweets(st, xs.g_hi, xs.g_lo, xs.t_valid)
+        with jax.named_scope("ingest.maintenance"):
+            st = _tick_maintenance(st, base)
+        return st._replace(tick=st.tick + 1)
 
-    return body
-
-
-def make_sharded_step(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
-    """Build the jitted sharded ingest step (query path)."""
-    n = mesh.shape[axis]
-    body = _ingest_body(cfg, n, axis)
-    rep = P()
-    state_spec = _state_spec(cfg, axis)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(state_spec, rep, rep, rep, rep, rep, rep),
-                   out_specs=state_spec,
-                   check_vma=False)
-    return jax.jit(fn)
+    return tick
 
 
 def _tick_maintenance(state: ShardedState, base: EngineConfig
@@ -235,8 +298,7 @@ def _tick_maintenance(state: ShardedState, base: EngineConfig
     ``engine.maintenance_cadence`` ladder (ONE copy of the cadence
     semantics) with sharded branch bodies — lazy: prune-only sweeps at
     ``prune_every``, session eviction at ``decay_every``; eager: full
-    decay/prune + eviction at ``decay_every``. Runs inside the replay scan
-    so replayed ticks mutate state exactly as live ones do."""
+    decay/prune + eviction at ``decay_every``."""
 
     def evict_only(s: ShardedState) -> ShardedState:
         sessions = stores.evict_sessions(s.sessions, s.tick, base.session_ttl)
@@ -271,100 +333,86 @@ def _tick_maintenance(state: ShardedState, base: EngineConfig
 
 def make_sharded_tick_step(cfg: ShardedConfig, mesh: Mesh,
                            axis: str = "shard"):
-    """One full live tick (ingest + cadence maintenance + tick advance) —
-    the sharded equivalent of ``SearchAssistanceEngine.step``'s state
-    mutations, so drivers using it replay exactly under
+    """One live tick, ``(state, tick) -> state``: ``tick`` is one tick of a
+    ``TickStack`` (no leading dim), queries and tweets. Its body is the
+    replay scan's, so live ticks replay exactly under
     ``make_sharded_ingest_many``."""
-    n = mesh.shape[axis]
-    base = cfg.base
-    ingest = _ingest_body(cfg, n, axis)
-
-    def body(state: ShardedState, s_hi, s_lo, q_hi, q_lo, src, valid):
-        state = ingest(state, s_hi, s_lo, q_hi, q_lo, src, valid)
-        state = _tick_maintenance(state, base)
-        return state._replace(tick=state.tick + 1)
-
-    rep = P()
-    state_spec = _state_spec(cfg, axis)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(state_spec, rep, rep, rep, rep, rep, rep),
-                   out_specs=state_spec, check_vma=False)
-    return jax.jit(fn)
+    spec = _state_spec(cfg, axis)
+    return jax.jit(shard_map(_tick_body(cfg, mesh.shape[axis], axis),
+                             mesh=mesh, in_specs=(spec, P()),
+                             out_specs=spec, check_vma=False))
 
 
 def make_sharded_ingest_many(cfg: ShardedConfig, mesh: Mesh,
                              axis: str = "shard"):
-    """Fused catch-up replay over the sharded engine (§4.2).
+    """Fused catch-up replay over the sharded engine (§4.2):
+    ``(state, stack) -> state`` for a ``TickStack`` of R logged ticks, the
+    type ``streaming.chunk_to_stack`` returns, so one durable log feeds
+    this and ``engine.ingest_many`` alike.
 
-    Each shard consumes the full logged hose (the paper's replicated-
-    consumption design), so ONE shared firehose log serves every shard and
-    replay is parallel by construction: a single ``lax.scan`` dispatch
-    advances all shards through a chunk of R logged ticks — per-tick
-    routing ``all_to_all``s included — with the cadence maintenance run
-    in-scan (identical state mutations to the live tick step above).
-
-    Takes stacked query-hose arrays ``[R, B]``; returns the advanced state.
+    Every shard takes the whole logged hose, so replay is parallel by
+    construction: one ``lax.scan`` dispatch advances all shards through
+    the R ticks — routing ``all_to_all``s and cadence maintenance
+    included, the live tick step's body per tick. The program is named
+    ``sharded_ingest_many`` in traces.
     """
-    n = mesh.shape[axis]
-    base = cfg.base
-    ingest = _ingest_body(cfg, n, axis)
+    spec = _state_spec(cfg, axis)
+    tick = _tick_body(cfg, mesh.shape[axis], axis)
 
-    def many(state: ShardedState, s_hi, s_lo, q_hi, q_lo, src, valid):
-        def scan_body(st, xs):
-            st = ingest(st, *xs)
-            st = _tick_maintenance(st, base)
-            return st._replace(tick=st.tick + 1), None
-
-        state, _ = jax.lax.scan(
-            scan_body, state, (s_hi, s_lo, q_hi, q_lo, src, valid))
+    def many(state: ShardedState, stack: TickStack) -> ShardedState:
+        state, _ = jax.lax.scan(lambda st, xs: (tick(st, xs), None), state,
+                                stack)
         return state
 
-    rep = P()
-    state_spec = _state_spec(cfg, axis)
-    fn = shard_map(many, mesh=mesh,
-                   in_specs=(state_spec, rep, rep, rep, rep, rep, rep),
-                   out_specs=state_spec, check_vma=False)
-    return jax.jit(fn)
+    program = shard_map(many, mesh=mesh, in_specs=(spec, P()),
+                        out_specs=spec, check_vma=False)
+
+    def sharded_ingest_many(state: ShardedState, stack: TickStack
+                            ) -> ShardedState:
+        return program(state, stack)
+
+    return jax.jit(sharded_ingest_many)
 
 
-def make_sharded_decay(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
-    base = cfg.base
+class ShardedEngine:
+    """Host driver of one sharded instance, as ``SearchAssistanceEngine``
+    is of one chip's: the state on ``mesh`` and its replay program.
 
-    def body(state: ShardedState, dticks):
-        # same fast paths as the unsharded engine: cfg.use_kernel routes the
-        # per-shard sweep through the fused multi-lane Pallas kernel; under
-        # the lazy policy this degrades to the prune-only sweep (run it at
-        # the prune_every cadence, not decay_every).
-        if base.lazy_decay:
-            qstore, _, _, _ = prune_sweep(state.qstore, state.tick,
-                                          cfg=base.decay)
-            if base.region_cooc:
-                cooc, _, _, _ = region_prune_sweep(
-                    state.cooc, qstore, state.tick, cfg=base.decay)
-            else:
-                cooc, _, _, _ = prune_sweep(state.cooc, state.tick,
-                                            cfg=base.decay)
-        else:
-            qstore, _, _ = sweep_decay_prune(
-                state.qstore, dticks, cfg=base.decay,
-                use_kernel=base.kernel_on("decay_prune"))
-            if base.region_cooc:
-                cooc, _, _, _ = region_decay_sweep(
-                    state.cooc, qstore, dticks, cfg=base.decay)
-            else:
-                cooc, _, _ = sweep_decay_prune(
-                    state.cooc, dticks, cfg=base.decay,
-                    use_kernel=base.kernel_on("decay_prune"))
-        sessions = stores.evict_sessions(state.sessions, state.tick,
-                                         base.session_ttl)
-        return ShardedState(qstore, cooc, sessions, state.tick + 0,
-                            state.n_route_drop)
+    ``step_many`` reads the tick and the routing counters in one
+    ``engine._sync`` per call and counts, per call: ``shard.route_pairs``
+    (pairs routed, all shards), ``shard.route_drops``, ``shard.route_bytes``
+    (bytes the call's ``all_to_all``s sent off each chip),
+    ``shard.pairs_max`` and ``shard.pairs_mean`` (pairs received by the
+    busiest shard, and by the mean one).
+    """
 
-    rep, sh = P(), P(axis)
-    state_spec = _state_spec(cfg, axis)
-    fn = shard_map(body, mesh=mesh, in_specs=(state_spec, rep),
-                   out_specs=state_spec, check_vma=False)
-    return jax.jit(fn)
+    def __init__(self, cfg: ShardedConfig, mesh: Mesh):
+        self.cfg = cfg
+        self.n_shards = n = mesh.shape["shard"]
+        self.state = init_sharded_state(cfg, mesh)
+        self.ingest_many = make_sharded_ingest_many(cfg, mesh)
+        # the tick and routing counters as last read: a fresh state's
+        self._seen = (0, np.zeros(n, np.uint32), np.zeros(n, np.int32))
+
+    @property
+    def tick(self) -> int:
+        return int(self._seen[0])
+
+    def step_many(self, stack: TickStack) -> None:
+        """Replay ``stack``, ranking suppressed, as
+        ``SearchAssistanceEngine.step_many`` does."""
+        st = self.state = self.ingest_many(self.state, stack)
+        (_, routed0, drops0), self._seen = self._seen, _sync(
+            (st.tick, st.n_routed, st.n_route_drop))
+        _, routed, drops = self._seen
+        got = (routed - routed0).astype(np.int64)   # u32: exact across a wrap
+        obs.count("shard.route_pairs", int(got.sum()))
+        obs.count("shard.route_drops", int(drops.sum() - drops0.sum()))
+        obs.count("shard.route_bytes", stack.n_ticks * route_bytes_per_tick(
+            self.cfg, self.n_shards, stack.q_hi.shape[1], stack.g_hi.shape[1],
+            stack.g_hi.shape[2]))
+        obs.count("shard.pairs_max", int(got.max()))
+        obs.count("shard.pairs_mean", float(got.mean()))
 
 
 def make_sharded_rank(cfg: ShardedConfig, mesh: Mesh, axis: str = "shard"):
@@ -405,6 +453,7 @@ def _state_spec(cfg: ShardedConfig, axis: str) -> ShardedState:
         sessions=jax.tree.map(lambda _: sh, stores.make_session_table(2, 2)),
         tick=rep,
         n_route_drop=sh,
+        n_routed=sh,
     )
 
 
@@ -692,7 +741,8 @@ def reshard_sharded_state(cfg: ShardedConfig, state: ShardedState,
         cooc=_stack_trees(coocs),
         sessions=_stack_trees(sessions),
         tick=state.tick,
-        n_route_drop=jnp.zeros((new_n,), jnp.int32))
+        n_route_drop=jnp.zeros((new_n,), jnp.int32),
+        n_routed=jnp.zeros((new_n,), jnp.uint32))
     # hand back UNCOMMITTED arrays: leaves assembled here inherit the OLD
     # mesh's placement (and the qstore its old replication), which the new
     # layout's shard_map would reject — round-tripping through host leaves

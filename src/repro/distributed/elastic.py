@@ -144,14 +144,14 @@ def live_reshard(cfg, state, new_n: int, mesh, *, log_dir: Optional[str] = None,
     Re-partitions ``state`` to ``new_n`` shards, then (when ``log_dir`` is
     given) catches the new state up through the shared firehose log's tail
     — the ticks that arrived while the repartition ran and the old state
-    kept serving them. Returns ``(new_state, stats)``; the caller swaps
-    serving over once ``stats["replayed_ticks"]`` has covered its head.
+    kept serving them, queries and tweets alike. Returns ``(new_state,
+    stats)``; the caller swaps serving over once ``stats["replayed_ticks"]``
+    has covered its head.
     ``mesh`` must span ``new_n`` devices along ``axis`` — the replay runs
     under the new layout's fused scan, whose per-tick state mutations are
     identical to the live sharded tick step (that is the bit-exactness
     property the handoff leans on).
     """
-    from ..core.hashing import split_fp
     from ..core.sharded_engine import (make_sharded_ingest_many,
                                       reshard_sharded_state)
     assert mesh.shape[axis] == new_n, \
@@ -160,6 +160,7 @@ def live_reshard(cfg, state, new_n: int, mesh, *, log_dir: Optional[str] = None,
     stats["replayed_ticks"] = 0
     if log_dir is not None:
         from ..streaming.log import FirehoseLogReader
+        from ..streaming.replay import chunk_to_stack
         reader = FirehoseLogReader(log_dir, name=log_name)
         head = reader.last_tick()
         t0 = int(jnp.asarray(new_state.tick))
@@ -167,12 +168,6 @@ def live_reshard(cfg, state, new_n: int, mesh, *, log_dir: Optional[str] = None,
             ingest = make_sharded_ingest_many(cfg, mesh, axis)
             for chunk in reader.read_chunks(t0, chunk_ticks,
                                             upto_tick=head + 1):
-                s_hi, s_lo = split_fp(chunk.sess_fp)
-                q_hi, q_lo = split_fp(chunk.q_fp)
-                new_state = ingest(
-                    new_state, jnp.asarray(s_hi), jnp.asarray(s_lo),
-                    jnp.asarray(q_hi), jnp.asarray(q_lo),
-                    jnp.asarray(chunk.src, jnp.int32),
-                    jnp.asarray(chunk.q_valid))
+                new_state = ingest(new_state, chunk_to_stack(chunk))
                 stats["replayed_ticks"] += chunk.n_ticks
     return new_state, stats

@@ -123,7 +123,7 @@ from .log import (FirehoseLogReader, FirehoseLogWriter, LogChunk,
 from .overload import (DegradationLadder, LatencyTracker, OverloadController,
                        SLOConfig, admit_events, admit_tweets)
 from .replay import (CatchUpController, ReplayConfig, chunk_to_stack,
-                     recover_engine, recover_service)
+                     recover_engine, recover_service, ticks_to_stack)
 from .workload import (FirehoseWorkload, SpamSpec, SpikeSpec, WorkloadConfig,
                        bucket_size)
 
@@ -136,7 +136,7 @@ __all__ = [
     "CompactionConfig", "LogCompactor", "corrupt_base",
     "restore_from_base",
     "CatchUpController", "ReplayConfig", "chunk_to_stack", "recover_engine",
-    "recover_service",
+    "recover_service", "ticks_to_stack",
     "OverloadController", "SLOConfig", "DegradationLadder", "LatencyTracker",
     "admit_events", "admit_tweets",
     "FirehoseWorkload", "WorkloadConfig", "SpikeSpec", "SpamSpec",

@@ -81,6 +81,18 @@ def chunk_to_stack(chunk: LogChunk) -> TickStack:
             t_valid=jnp.asarray(chunk.t_valid))
 
 
+def ticks_to_stack(ticks) -> TickStack:
+    """Consecutive live ticks, ``(QueryEvents, TweetBatch)`` pairs, as the
+    ``TickStack`` that replaying them from the log feeds."""
+    col = lambda f: np.stack([f(ev, tw) for ev, tw in ticks])
+    return chunk_to_stack(LogChunk(
+        ticks=np.arange(len(ticks)), sess_fp=col(lambda ev, tw: ev.sess_fp),
+        q_fp=col(lambda ev, tw: ev.q_fp), src=col(lambda ev, tw: ev.src),
+        q_valid=col(lambda ev, tw: ev.valid),
+        grams=col(lambda ev, tw: tw.grams),
+        t_valid=col(lambda ev, tw: tw.valid)))
+
+
 class CatchUpController:
     """Drives one engine from its restored offset to the log head."""
 
